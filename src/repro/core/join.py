@@ -302,8 +302,8 @@ def plan_join(dataset, oracle, proposer, extractor, cfg: FDJConfig, *,
         d2 = extractor.pair_distances(used_specs, s2, ledger)
         cd2 = sc_local.clause_distances(d2)
         # Eq-4 selection goes through the device sweep (threshold_sweep
-        # kernel grid + coordinate refinement; greedy remains the numpy
-        # fallback and the never-worse A/B baseline)
+        # kernel grid + coordinate refinement; greedy remains the
+        # never-worse A/B baseline)
         thr = min_fpr_thresholds(cd2, y2, t_prime, method="auto")
         theta = thr.theta
         feasible = thr.feasible

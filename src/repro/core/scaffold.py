@@ -11,9 +11,9 @@ exhaustive for 1 clause (Appx G pruning makes this O(k log k)); for more
 clauses, ``method`` selects between two routes:
 
   * ``"greedy"`` — the Alg-8 greedy coordinate descent from +inf, with
-    swap-repair local search (the numpy fallback, and the cheap route
-    Alg-4 scaffold *cost estimation* stays on: it only needs relative
-    ordering across candidate scaffolds, not the tightest theta);
+    swap-repair local search (the cheap route Alg-4 scaffold *cost
+    estimation* stays on: it only needs relative ordering across
+    candidate scaffolds, not the tightest theta);
   * ``"device"`` — the ``kernels/threshold_sweep`` path: a capped
     cartesian grid of per-clause positive-distance quantiles
     (``candidate_grid``) is swept in one ``pallas_call`` (all (pos, sel)
@@ -21,8 +21,8 @@ clauses, ``method`` selects between two routes:
     seeds a greedy coordinate refinement, and the result is A/B'd against
     the plain greedy descent — the device route never returns a worse
     feasible FPR than the greedy baseline, by construction;
-  * ``"auto"`` — ``"device"`` when the sweep kernel's stack imports,
-    else ``"greedy"`` (the guarantee path — Eq-4 selection in plan_join
+  * ``"auto"`` — ``"device"`` for two or more clauses, the exhaustive
+    1-D sweep for one (the guarantee path — Eq-4 selection in plan_join
     and serving-time recalibration — passes this).
 
 Candidate thresholds are exactly the positive pairs' distances — pushing a
@@ -116,31 +116,17 @@ def min_fpr_thresholds(cd: np.ndarray, labels: np.ndarray, target: float,
                 best = cand
     if method == "greedy":
         return best
-    dev = _device_sweep(cd, labels, pos, need, n_pos,
-                        required=(method == "device"))
-    if dev is None:                                 # auto: kernel unavailable
-        return best
+    dev = _device_sweep(cd, labels, pos, need, n_pos)
     if dev.feasible and (not best.feasible or dev.fpr < best.fpr - 1e-12):
         return dev
     return best
 
 
 def _device_sweep(cd: np.ndarray, labels: np.ndarray, pos: np.ndarray,
-                  need: int, n_pos: int, *, required: bool):
+                  need: int, n_pos: int):
     """Grid sweep on device (kernels/threshold_sweep) + coordinate
-    refinement around the argmin-FPR feasible grid point.
-
-    Returns None when the sweep stack cannot import and the caller asked
-    for "auto" (the numpy greedy remains the fallback); ``required=True``
-    re-raises instead — "device" was requested explicitly.
-    """
-    try:
-        from repro.kernels.threshold_sweep.ops import (candidate_grid,
-                                                       sweep_counts)
-    except Exception:
-        if required:
-            raise
-        return None
+    refinement around the argmin-FPR feasible grid point."""
+    from repro.kernels.threshold_sweep.ops import candidate_grid, sweep_counts
     grid = candidate_grid(pos)
     pos_counts, sel_counts = sweep_counts(cd, labels, grid)
     k = cd.shape[0]

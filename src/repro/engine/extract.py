@@ -4,18 +4,25 @@ The fused kernel emits a uint32 mask packed 32 R-neighbours per word.
 Pulling that mask to the host costs n_l·n_r/8 bytes regardless of how few
 pairs survive — at corpus scale the transfer, not the kernel, dominates.
 ``compact_append`` turns the mask into a dense buffer of (i, j) index
-pairs *on the device* via popcount + prefix-sum compaction:
+pairs *on the device* via popcount + prefix-sum compaction, written as a
+gather so its cost follows the buffer, not the mask:
 
-  1. ``lax.population_count`` per word  -> per-word candidate counts;
-  2. exclusive prefix-sum over words (row-major) -> per-word base offsets;
-  3. per-word bit expansion + intra-word exclusive prefix-sum -> bit slots;
-  4. scatter (i, j) into the output buffer at base+slot (OOB writes drop).
+  1. ``lax.population_count`` per word -> inclusive prefix sum over words
+     (row-major);
+  2. for every buffer slot, a binary search of that prefix sum finds the
+     word holding the slot's set bit, and the in-word prefix count picks
+     the bit;
+  3. slots past the candidates keep the buffer's previous contents.
 
-The buffer has a fixed capacity (scatter targets must be static under
-jit); overflow is *detected, never silent* — the returned count keeps
-growing past capacity, so the caller compares count vs capacity and
-retries bigger.  Host traffic becomes O(candidates): one scalar count plus
-8 bytes per surviving pair.
+A scatter of every bit of the mask would cost O(n_l·n_r) scatter
+updates — about 3 s per 100,352 x 512 band step on a TPU v5e, against
+72 ms for this gather, whose cost is O(capacity · log(words) + words).
+
+The buffer has a fixed capacity (shapes must be static under jit);
+overflow is *detected, never silent* — the returned count keeps growing
+past capacity, so the caller compares count vs capacity and retries
+bigger.  Host traffic becomes O(candidates): one scalar count plus 8
+bytes per surviving pair.
 """
 
 from __future__ import annotations
@@ -54,7 +61,7 @@ def compact_append(packed, buf, count, *, row_offset=0, col_offset=0):
     """Append the set bits of ``packed`` to ``buf`` as (i, j) pairs.
 
     packed: uint32 (nl, nw) mask (nw words of 32 R-columns each)
-    buf:    int32 (capacity, 2) output buffer (scatter target)
+    buf:    int32 (capacity, 2) output buffer
     count:  int32 scalar — pairs already in ``buf``; the write cursor
     row_offset/col_offset: global coordinates of packed[0, 0]'s bit 0
       (traced values are fine — e.g. ``lax.axis_index`` inside shard_map)
@@ -63,24 +70,23 @@ def compact_append(packed, buf, count, *, row_offset=0, col_offset=0):
     the tail was dropped and the caller must retry with a larger buffer.
     """
     capacity = buf.shape[0]
-    nl, nw = packed.shape
-    counts = lax.population_count(packed).astype(jnp.int32)          # (nl, nw)
-    flat = counts.reshape(-1)
-    word_base = (jnp.cumsum(flat) - flat).reshape(nl, nw)            # exclusive
-    bitpos = jnp.arange(32, dtype=jnp.uint32)
-    bits = ((packed[:, :, None] >> bitpos) & jnp.uint32(1)).astype(jnp.int32)
-    intra = jnp.cumsum(bits, axis=-1) - bits                         # exclusive
-    pos = count + word_base[:, :, None] + intra                      # (nl,nw,32)
-    pos = jnp.where(bits == 1, pos, capacity)                        # unset -> OOB
-    rows = jnp.broadcast_to(
-        jnp.arange(nl, dtype=jnp.int32)[:, None, None] + row_offset, bits.shape)
-    cols = jnp.broadcast_to(
-        jnp.arange(nw, dtype=jnp.int32)[None, :, None] * 32
-        + jnp.arange(32, dtype=jnp.int32)[None, None, :] + col_offset,
-        bits.shape)
-    pairs = jnp.stack([rows, cols], axis=-1).reshape(-1, 2)
-    buf = buf.at[pos.reshape(-1)].set(pairs, mode="drop")
-    return buf, count + flat.sum()
+    nw = packed.shape[1]
+    flat = packed.reshape(-1)
+    counts = lax.population_count(flat).astype(jnp.int32)
+    cum = jnp.cumsum(counts)                                         # inclusive
+    total = cum[-1]
+    slot = jnp.arange(capacity, dtype=jnp.int32) - count   # rank of the bit
+    word = jnp.clip(jnp.searchsorted(cum, slot, side="right"),
+                    0, flat.shape[0] - 1).astype(jnp.int32)
+    rank = slot - (cum[word] - counts[word])                         # in word
+    bits = ((flat[word][:, None] >> jnp.arange(32, dtype=jnp.uint32))
+            & jnp.uint32(1)).astype(jnp.int32)                       # (cap,32)
+    bit = jnp.sum(jnp.cumsum(bits, axis=-1) <= rank[:, None], axis=-1,
+                  dtype=jnp.int32)
+    pairs = jnp.stack([word // nw + row_offset,
+                       (word % nw) * 32 + bit + col_offset], axis=-1)
+    fill = (slot >= 0) & (slot < total)
+    return jnp.where(fill[:, None], pairs, buf), count + total
 
 
 def hierarchical_offsets(count, *, inner_axes, inner_index, pod_axis=None):

@@ -107,7 +107,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.engine import extract
@@ -316,11 +315,10 @@ class ShardedEngine(CnfEngine):
             # alongside the counts, and converts units to pair-clause
             # evals.
             if use_kernel:
-                packed, evals_grid = cnf_join_block(
+                packed, evals = cnf_join_block(
                     emb_l, erk, scal_l, srk, kclauses, thetas, tl=tl, tr=tr,
                     interpret=interpret, early_reject=early_reject,
                     with_evals=True)
-                evals = jnp.sum(evals_grid, dtype=jnp.int32)
             else:
                 ok, evals = cref.cnf_join_ref_counted(
                     emb_l, erk, scal_l, srk, kclauses, thetas,
@@ -337,13 +335,13 @@ class ShardedEngine(CnfEngine):
 
         row_spec = l_axes[0] if len(l_axes) == 1 else l_axes
         dev_axes = l_axes + (("model",) if has_model else ())
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, row_spec, None), P(None, None, None),
                       P(None, row_spec), P(None, None), P()),
             out_specs=(P(dev_axes, None), P(dev_axes), P(dev_axes),
                        P(dev_axes)),
-            check_rep=False)   # pallas_call has no replication rule
+            check_vma=False)   # pallas_call has no replication rule
         return jax.jit(fn)
 
     # -- evaluation ---------------------------------------------------------

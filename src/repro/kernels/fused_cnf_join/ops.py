@@ -326,14 +326,14 @@ def evaluate_corpus_stream(feats: Sequence, clauses: Sequence, thetas,
     for i0 in range(0, pl_n, l_block):
         rows = min(l_block, pl_n - i0)
         t0 = time.perf_counter()
-        packed, evals_grid = cnf_join_block(
+        packed, evals = cnf_join_block(
             lax.slice_in_dim(demb_l, i0, i0 + rows, axis=1), demb_r,
             lax.slice_in_dim(dscal_l, i0, i0 + rows, axis=1), dscal_r,
             kclauses, thetas, tl=tl, tr=tr, interpret=interpret,
             early_reject=early_reject, with_evals=True)
         t1 = time.perf_counter()
         host_mask = np.asarray(packed)              # O(rows * n_r / 8) pull
-        evals_host = np.asarray(evals_grid)         # one int32 per tile
+        evals_host = np.asarray(evals)              # clauses over all tiles
         t2 = time.perf_counter()
         ok = ref.unpack_mask(host_mask, pr_n)[: max(n_l - i0, 0), :n_r]
         ii, jj = np.nonzero(ok)
@@ -353,5 +353,5 @@ def evaluate_corpus_stream(feats: Sequence, clauses: Sequence, thetas,
             list(zip((ii + i0).tolist(), jj.tolist())),
             bytes_to_host=host_mask.nbytes + evals_host.nbytes,
             bytes_h2d=h2d if i0 == 0 else 0,
-            conjunct_evals=int(evals_host.sum()) * tl * tr,
+            conjunct_evals=int(evals_host) * tl * tr,
             trace=trace)

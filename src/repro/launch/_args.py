@@ -11,6 +11,7 @@ added so every launcher inherits it.
 from __future__ import annotations
 
 import argparse
+import os
 from typing import Optional
 
 from repro.data import synth
@@ -37,13 +38,29 @@ def make_dataset(name: str, *, size: float = 1.0, seed: int = 0,
     return gens[name]()
 
 
-def add_common_flags(ap: argparse.ArgumentParser, *,
-                     engine_default: str = "numpy"
-                     ) -> argparse.ArgumentParser:
-    """The flag set every join launcher shares."""
+def use_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache at a fixed path.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, places the cache and nothing is
+    set here.  Otherwise the cache lives at ``<checkout>/.cache/jax``,
+    derived from this file's location the way ``core.adj_target.cache_dir``
+    derives its curve cache: the directory is part of the cache key, so it
+    must not move between runs of the same checkout.
+    """
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
+    jax.config.update("jax_compilation_cache_dir",
+                      os.path.join(root, ".cache", "jax"))
+
+
+def add_common_flags(ap: argparse.ArgumentParser) -> argparse.ArgumentParser:
+    """The flag set every join launcher shares.  ``--engine`` defaults to
+    the sharded engine, so the launchers run step ② on the device."""
     ap.add_argument("--dataset", default="police_records")
-    ap.add_argument("--engine", default=engine_default,
-                    choices=list(ENGINES))
+    ap.add_argument("--engine", default="sharded", choices=list(ENGINES))
     ap.add_argument("--stream", action="store_true",
                     help="pipeline refinement over the step-② candidate "
                          "stream (FDJConfig.stream_refinement)")

@@ -27,7 +27,9 @@ def run_dryrun(mesh: str, *extra: str, timeout: int = 420,
     report.  Raises AssertionError (with captured output) when the
     subprocess exits nonzero, reports a failed status, or emits no
     marker line."""
-    env = dict(os.environ)
+    # the child is a CPU emulation: it must never reach for an accelerator,
+    # which the parent process may already hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.path.join(repo, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     proc = subprocess.run(

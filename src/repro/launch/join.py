@@ -1,7 +1,10 @@
 """FDJ join launcher — the paper's end-to-end pipeline as a CLI.
 
   PYTHONPATH=src python -m repro.launch.join --dataset police_records \
-      --target 0.9 --delta 0.1 [--engine numpy|pallas|sharded]
+      --target 0.9 --delta 0.1 [--engine sharded|numpy|pallas]
+
+``--engine`` defaults to ``sharded``: step ② runs on the local devices
+(the fused Pallas kernel, compiled on a TPU, interpreted on the CPU).
 
 Also exposes the *distributed join step* (``build_join_cell``): the fused
 CNF evaluation over an L x R block plane lowered on the production mesh —
@@ -21,7 +24,7 @@ from repro.core.costs import naive_join_cost
 from repro.core.join import FDJConfig, fdj_join
 from repro.data.simulated_llm import SimulatedExtractor, SimulatedProposer
 from repro.launch._args import (add_common_flags, engine_opts_from,
-                                make_dataset)
+                                make_dataset, use_compile_cache)
 from repro.obs import Tracer, use_tracer, write_trace
 
 
@@ -110,6 +113,7 @@ def main():
                          "needs enough devices — see launch/multipod_dryrun "
                          "for the emulated (2, 16, 16) dry-run)")
     args = ap.parse_args()
+    use_compile_cache()
     out = run_join(args.dataset, args.target, args.delta,
                    args.precision_target, args.engine, args.size, args.seed,
                    stream=args.stream, pods=args.pods,

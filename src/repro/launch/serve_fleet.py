@@ -20,7 +20,7 @@ import json
 
 from repro.core.join import FDJConfig
 from repro.launch._args import (add_common_flags, engine_opts_from,
-                                make_dataset)
+                                make_dataset, use_compile_cache)
 from repro.launch.serve_join import SERVE_SCALE
 from repro.obs import Tracer, use_tracer, write_trace
 from repro.serving.fleet import JoinFleet
@@ -87,7 +87,7 @@ def run_fleet(dataset: str = "police_records", engine: str = "sharded",
 
 
 def main():
-    ap = add_common_flags(argparse.ArgumentParser(), engine_default="sharded")
+    ap = add_common_flags(argparse.ArgumentParser())
     ap.add_argument("--tenants", type=int, default=2)
     ap.add_argument("--queries", type=int, default=2,
                     help="queries submitted per tenant")
@@ -105,6 +105,7 @@ def main():
                     help="simulated L_p round-trip seconds per labeled "
                          "pair (GIL-released; see SimulatedOracle)")
     args = ap.parse_args()
+    use_compile_cache()
     run_fleet(args.dataset, args.engine, args.stream, args.size, args.target,
               args.delta, args.seed, args.tenants, args.queries, args.shared,
               args.max_concurrent, args.byte_budget, args.tenant_budget,

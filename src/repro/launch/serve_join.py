@@ -1,7 +1,7 @@
 """Join-serving launcher: load a corpus, serve a scripted query stream.
 
   PYTHONPATH=src python -m repro.launch.serve_join --dataset police_records \
-      --engine sharded --holdout 40 \
+      --holdout 40 \
       --script "query,query,append=20,query,append,query@target=0.8"
 
 Script ops (comma-separated, run in order against one JoinService):
@@ -25,7 +25,7 @@ import json
 
 from repro.core.join import FDJConfig, QueryOptions
 from repro.launch._args import (add_common_flags, engine_opts_from,
-                                make_dataset)
+                                make_dataset, use_compile_cache)
 from repro.obs import Tracer, use_tracer, write_trace
 from repro.serving.join_service import DeltaRows, JoinService, hold_out_right
 from repro.serving.planes import FeaturePlaneStore
@@ -158,6 +158,7 @@ def main():
     ap.add_argument("--byte-budget", type=int, default=None,
                     help="plane-store device byte budget (LRU eviction)")
     args = ap.parse_args()
+    use_compile_cache()
     run_serve(args.dataset, args.engine, args.stream, args.size, args.target,
               args.delta, args.holdout, args.script, args.seed,
               args.byte_budget, engine_opts=engine_opts_from(args.r_chunk),

@@ -8,7 +8,8 @@ from repro.kernels.fused_cnf_join import ops as cnf_ops, ref as cnf_ref
 from repro.kernels.fused_cnf_join.kernel import SCAL, VEC, cnf_join_block
 from repro.kernels.threshold_sweep.ops import (candidate_grid, sweep,
                                                sweep_counts)
-from repro.kernels.threshold_sweep.ref import threshold_sweep_ref
+from repro.kernels.threshold_sweep.ref import (threshold_sweep_ref,
+                                               threshold_sweep_ref_jit)
 
 
 def _mk_inputs(rng, fv, fs, nl, nr, d, dtype):
@@ -62,6 +63,39 @@ def test_cnf_kernel_clause_structures(structure):
                           np.asarray(expect))
 
 
+@pytest.mark.parametrize("nl,nr,tl,tr", [
+    (24, 96, 8, 96),       # 3 words per tile: fewer than a sublane tile
+    (40, 160, 8, 32),      # one word per tile, 5 x 5 grid
+    (64, 192, 32, 64),
+    (16, 1024, 16, 512),   # 16 words per tile, the pallas R tile
+])
+@pytest.mark.parametrize("early_reject", [False, True])
+def test_cnf_kernel_layout_matches_ref(nl, nr, tl, tr, early_reject):
+    """Transposed packed-word output and the grid-summed eval counter on
+    ragged tile grids: the mask words are bit-identical to ``pack_mask``
+    of the jnp ref, and evals count 1 clause per tile whose first clause
+    passes nowhere (early reject) and every clause otherwise."""
+    rng = np.random.default_rng(nl * nr + tr)
+    el, er, sl, sr = (jnp.asarray(a) for a in
+                      _mk_inputs(rng, 2, 1, nl, nr, 128, np.float32))
+    clauses = (((VEC, 0),), ((VEC, 1), (SCAL, 0)))
+    thetas = (0.37, 0.45)           # a sparse first clause: some dead tiles
+    mask, evals = cnf_join_block(el, er, sl, sr, clauses, thetas, tl=tl,
+                                 tr=tr, interpret=True,
+                                 early_reject=early_reject, with_evals=True)
+    want = np.asarray(cnf_ref.pack_mask(
+        cnf_ref.cnf_join_ref(el, er, sl, sr, clauses, thetas)))
+    assert mask.dtype == jnp.uint32 and mask.shape == (nl, nr // 32)
+    assert np.array_equal(np.asarray(mask), want)
+    ok0 = np.asarray(cnf_ref.cnf_join_ref(el, er, sl, sr, clauses[:1],
+                                          thetas[:1]))
+    live = ok0.reshape(nl // tl, tl, nr // tr, tr).any(axis=(1, 3))
+    n_c = len(clauses)
+    want_evals = np.where(live, n_c, 1).sum() if early_reject \
+        else n_c * live.size
+    assert int(evals) == want_evals
+
+
 def test_cnf_corpus_vs_numpy_join_path():
     """evaluate_corpus (padding, packing, missing encoding) == numpy engine."""
     from repro.core.costs import CostLedger
@@ -99,10 +133,10 @@ def test_threshold_sweep(k, c, g):
     labels = rng.random(k) < 0.3
     th = rng.uniform(0, 1, size=(g, c)).astype(np.float32)
     pos, sel = sweep(cd, labels, th, tg=64, tk=256)
-    expect = np.asarray(threshold_sweep_ref(
+    expect = np.asarray(threshold_sweep_ref_jit(
         jnp.asarray(cd), jnp.asarray(labels.astype(np.float32)), jnp.asarray(th)))
-    np.testing.assert_allclose(pos, expect[:, 0], rtol=1e-6)
-    np.testing.assert_allclose(sel, expect[:, 1], rtol=1e-6)
+    np.testing.assert_array_equal(pos, expect[:, 0])     # bit for bit
+    np.testing.assert_array_equal(sel, expect[:, 1])
 
 
 def test_threshold_sweep_grid_helper():
