@@ -10,7 +10,7 @@ compute in the system, so it gets its own subsystem with three backends:
   * ``numpy``   — single-host blocked loop (reference semantics)
   * ``pallas``  — single-device fused kernel, packed-bitmask host transfer
   * ``sharded`` — shard_map over the mesh "data" axis with on-device
-                  candidate extraction; host traffic is O(candidates)
+                  candidate extraction; the host keeps O(candidates)
 
 All backends must return the *identical* candidate set for identical
 inputs (guarded by tests/test_engines.py).  Engines also report
@@ -177,15 +177,20 @@ class ChunkDelta:
     pull_s: float = 0.0                # host time pulling + filtering
     overlap_s: float = 0.0             # host work done with a step in flight
     conjunct_evals: int = 0            # (pair, clause) evals this chunk did
+    # device programs this chunk's dispatches ran for the first time, i.e.
+    # compiled (None: the backend does not count them)
+    programs_built: Optional[int] = None
     # optional tracing payload (DESIGN.md §7) — backends that measure their
     # own sub-phase timestamps attach them here and ``_stream_checked``
     # turns them into child slices of the chunk's ``band_step[k]`` span.
-    # ``trace`` is a list of ``{"name", "t0", "t1", "attrs"}`` dicts (perf-
-    # counter seconds), ``trace_events`` a list of ``(name, ts, attrs)``
-    # instants (overflow / invalidate / redispatch), ``track`` the
-    # rendering lane (the sharded ring uses one lane per ring slot so
-    # concurrent steps render side by side instead of mis-nesting).  All
-    # three are ignored — and should stay None — when tracing is off.
+    # ``trace`` is a list of ``{"name", "t0", "t1", "attrs", "children"}``
+    # dicts (perf-counter seconds; ``attrs`` and ``children``, a list of
+    # such dicts recorded under that slice, optional), ``trace_events`` a
+    # list of ``(name, ts, attrs)`` instants (overflow / invalidate /
+    # redispatch), ``track`` the rendering lane (the sharded ring uses one
+    # lane per ring slot so concurrent steps render side by side instead
+    # of mis-nesting).  All three are ignored — and should stay None —
+    # when tracing is off.
     trace: Optional[list] = None
     trace_events: Optional[list] = None
     track: Optional[str] = None
@@ -275,11 +280,13 @@ class CnfEngine(abc.ABC):
                 self._evaluate_stream(feats, clauses, thetas, n_l, n_r)):
             if not isinstance(delta, ChunkDelta):
                 delta = ChunkDelta(*delta)
-            pairs = sorted(delta.pairs)
+            t_sort0 = time.perf_counter()
+            with tracer.annotate("sort_pairs"):
+                pairs = sorted(delta.pairs)
             t_now = time.perf_counter()
             if tracer:
                 self._trace_band_step(tracer, idx, delta, len(pairs),
-                                      t_prev, t_now)
+                                      t_prev, t_sort0, t_now)
             yield CandidateChunk(
                 pairs, EngineStats(self.name, n_l=n_l, n_r=n_r,
                                    n_candidates=len(pairs),
@@ -294,23 +301,34 @@ class CnfEngine(abc.ABC):
             t_prev = time.perf_counter()
 
     def _trace_band_step(self, tracer: Tracer, idx, delta, n_pairs,
-                         t_prev, t_now):
+                         t_prev, t_sort0, t_now):
         """Record one chunk's ``band_step[idx]`` span plus any backend-
-        provided sub-slices (sharded dispatch/pull windows).  The step span
-        opens at the earliest sub-slice start — for a prefetched ring step
-        that is the *enqueue* instant, which predates ``t_prev``, so steps
-        overlap in time and each rides its own ring-slot track."""
+        provided sub-slices (sharded dispatch/pull windows and the pull's
+        own parts) and the ``sort_pairs`` slice.  The step span opens at
+        the earliest sub-slice start — for a prefetched ring step that is
+        the *enqueue* instant, which predates ``t_prev``, so steps overlap
+        in time and each rides its own ring-slot track."""
         slices = delta.trace or ()
         t0 = min([t_prev] + [s["t0"] for s in slices])
+        attrs = {"engine": self.name, "candidates": n_pairs,
+                 "bytes_to_host": delta.bytes_to_host,
+                 "conjunct_evals": delta.conjunct_evals}
+        if delta.programs_built is not None:
+            attrs["programs_built"] = delta.programs_built
         step = tracer.record_span(
-            f"band_step[{idx}]", t0, t_now, track=delta.track,
-            attrs={"engine": self.name, "candidates": n_pairs,
-                   "bytes_to_host": delta.bytes_to_host,
-                   "conjunct_evals": delta.conjunct_evals},
+            f"band_step[{idx}]", t0, t_now, track=delta.track, attrs=attrs,
             events=delta.trace_events)
-        for s in slices:
-            tracer.record_span(s["name"], s["t0"], s["t1"], parent=step,
-                               track=delta.track, attrs=s.get("attrs"))
+
+        def record(slices, parent):
+            for s in slices:
+                sp = tracer.record_span(s["name"], s["t0"], s["t1"],
+                                        parent=parent, track=delta.track,
+                                        attrs=s.get("attrs"))
+                record(s.get("children") or (), sp)
+
+        record(slices, step)
+        tracer.record_span("sort_pairs", t_sort0, t_now, parent=step,
+                           track=delta.track)
 
     @abc.abstractmethod
     def _evaluate_stream(self, feats, clauses, thetas, n_l: int, n_r: int):

@@ -21,8 +21,9 @@ updates — about 3 s per 100,352 x 512 band step on a TPU v5e, against
 The buffer has a fixed capacity (shapes must be static under jit);
 overflow is *detected, never silent* — the returned count keeps growing
 past capacity, so the caller compares count vs capacity and retries
-bigger.  Host traffic becomes O(candidates): one scalar count plus 8
-bytes per surviving pair.
+bigger.  What the host keeps becomes O(candidates): one scalar count plus
+8 bytes per surviving pair (the sharded engine's copy still moves each
+non-empty shard's whole buffer, ``engine/sharded.py``).
 """
 
 from __future__ import annotations

@@ -38,9 +38,11 @@ Layout (see DESIGN.md §3):
     sorts, and the consumer holds the previous chunk — deeper rings
     ride out slower/burstier host pulls.  Per chunk the host pulls one
     int32 count, one int32 global base and one int32 conjunct-eval
-    counter per device plus the first ``count`` buffer rows
-    (``jax.device_get``): O(candidates) transfer total, and the first
-    candidates surface after one scan step.  Batch ``evaluate`` is a
+    counter per device, then copies each non-empty device's whole
+    ``(cap, 2)`` candidate buffer and keeps its first ``count`` rows:
+    O(capacity) bytes per non-empty shard on the link (the ``fetch``
+    span's ``bytes_moved``), O(candidates) kept (``bytes_to_host``), and
+    the first candidates surface after one scan step.  Batch ``evaluate`` is a
     drain of this same stream.  ``prefetch_depth=1`` (≡ the legacy
     ``double_buffer=False``) is the serial A/B control — the ring holds
     nothing while the host pulls or the consumer holds, so its
@@ -100,6 +102,7 @@ import contextlib
 import dataclasses
 import threading
 import time
+import weakref
 from typing import Optional
 
 import numpy as np
@@ -240,6 +243,25 @@ class ShardedEngine(CnfEngine):
     # insert (held through a cold compile too, so two threads racing the
     # same key compile once, not twice)
     _programs_lock = threading.Lock()
+    # each cached program's first-call argument shapes and shardings: a
+    # program not in it has not run yet (its next call compiles it), and
+    # its compiled text can be read again (band_step_hlo).  Entries go
+    # with their program.
+    _first_args = weakref.WeakKeyDictionary()
+
+    @classmethod
+    def band_step_hlo(cls) -> list:
+        """The optimized HLO text of every cached band-step program that
+        has run, compiled again at its first call's shapes and shardings
+        (a persistent-cache hit where JAX's compile cache is on).  Its
+        instruction names are those of a device trace's op events, and
+        their ``op_name`` metadata carries the body's named scopes
+        (``fdj_kernel`` / ``fdj_extract`` / ``fdj_offsets``)."""
+        with cls._programs_lock:
+            runs = [(fn, cls._first_args.get(fn))
+                    for fn in cls._programs.values()]
+        return [fn.lower(*specs).compile().as_text()
+                for fn, specs in runs if specs is not None]
 
     def _resolve_r_chunk(self, n_model: int) -> int:
         r_chunk = self.r_chunk if self.r_chunk else 4 * self.tr * n_model
@@ -314,23 +336,28 @@ class ShardedEngine(CnfEngine):
             # (no collective): the host pulls one int32 per device,
             # alongside the counts, and converts units to pair-clause
             # evals.
-            if use_kernel:
-                packed, evals = cnf_join_block(
-                    emb_l, erk, scal_l, srk, kclauses, thetas, tl=tl, tr=tr,
-                    interpret=interpret, early_reject=early_reject,
-                    with_evals=True)
-            else:
-                ok, evals = cref.cnf_join_ref_counted(
-                    emb_l, erk, scal_l, srk, kclauses, thetas,
-                    early_reject=early_reject)
-                packed = cref.pack_mask(ok)
-            buf, cnt = extract.extract_pairs(packed, capacity=cap,
-                                             row_offset=row0,
-                                             col_offset=col0)
-            base, _ = extract.hierarchical_offsets(
-                cnt, inner_axes=inner_axes,
-                inner_index=data * n_model + model,
-                pod_axis="pod" if has_pod else None)
+            # the named scopes label each part's operations in a device
+            # trace (their HLO op_name metadata)
+            with jax.named_scope("fdj_kernel"):
+                if use_kernel:
+                    packed, evals = cnf_join_block(
+                        emb_l, erk, scal_l, srk, kclauses, thetas, tl=tl,
+                        tr=tr, interpret=interpret,
+                        early_reject=early_reject, with_evals=True)
+                else:
+                    ok, evals = cref.cnf_join_ref_counted(
+                        emb_l, erk, scal_l, srk, kclauses, thetas,
+                        early_reject=early_reject)
+                    packed = cref.pack_mask(ok)
+            with jax.named_scope("fdj_extract"):
+                buf, cnt = extract.extract_pairs(packed, capacity=cap,
+                                                 row_offset=row0,
+                                                 col_offset=col0)
+            with jax.named_scope("fdj_offsets"):
+                base, _ = extract.hierarchical_offsets(
+                    cnt, inner_axes=inner_axes,
+                    inner_index=data * n_model + model,
+                    pod_axis="pod" if has_pod else None)
             return buf, cnt[None], base[None], evals[None]
 
         row_spec = l_axes[0] if len(l_axes) == 1 else l_axes
@@ -369,17 +396,23 @@ class ShardedEngine(CnfEngine):
         # rows) and R to a multiple of r_chunk (whole stream steps).
         # stage_planes uploads a host pack once directly onto the mesh
         # layout — or assembles on device from a resident plane set
-        # (serving store) with zero H2D, paying a one-time D2D reshard
-        # that is memoized on the plane set (warm queries: 0 bytes).
+        # (serving store) with zero H2D.  The assembly and its D2D reshard
+        # are memoized on that plane set: a query that hands over the same
+        # plane set again stages nothing, one with a new plane set (even
+        # over the same resident arrays) assembles again (bytes_staged).
         tracer = current_tracer()
         t_stage0 = time.perf_counter()
-        staged = cnf_ops.stage_planes(feats, clauses, tl=l_shards * self.tl,
-                                      tr=r_chunk, mesh=mesh, l_axes=l_axes)
+        with tracer.annotate("stage_planes"):
+            staged = cnf_ops.stage_planes(feats, clauses,
+                                          tl=l_shards * self.tl, tr=r_chunk,
+                                          mesh=mesh, l_axes=l_axes)
         if tracer:
             tracer.record_span(
                 "stage_planes", t_stage0, time.perf_counter(),
                 attrs={"bytes_h2d": staged.bytes_h2d,
-                       "bytes_reshard": staged.bytes_reshard})
+                       "bytes_reshard": staged.bytes_reshard,
+                       "bytes_staged": staged.bytes_staged,
+                       "pack_hit": staged.pack_hit})
         kclauses = staged.kclauses
         pl_n, pr_n = staged.emb_l.shape[1], staged.emb_r.shape[1]
         rows_shard = pl_n // l_shards
@@ -393,7 +426,7 @@ class ShardedEngine(CnfEngine):
         # dense join must not over-allocate every later query.
         caps = np.full(n_dev, self.capacity or max(4096, 4 * rows_shard),
                        np.int64)
-        timing = {"dispatch": 0.0}
+        timing = {"dispatch": 0.0, "built": 0}
         # host conversion factor from device eval *units* to (pair,
         # clause) evaluations: the kernel counts per tile, the jnp
         # reference per whole sub-band (see body)
@@ -413,16 +446,70 @@ class ShardedEngine(CnfEngine):
                     else contextlib.nullcontext():
                 fn = self._build(mesh, kclauses, thetas, rows_shard, cap,
                                  r_chunk, n_chunks)
-                buf, cnt, base, evals = fn(*args, jnp.int32(k))
+                first = fn not in ShardedEngine._first_args
+                # a program's first call traces and compiles it
+                with tracer.annotate("compile" if first else "enqueue"):
+                    buf, cnt, base, evals = fn(*args, jnp.int32(k))
+                if first:
+                    with ShardedEngine._programs_lock:
+                        ShardedEngine._first_args[fn] = tuple(
+                            jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                 sharding=a.sharding)
+                            for a in args) + (
+                                jax.ShapeDtypeStruct((), jnp.int32),)
             timing["dispatch"] += time.perf_counter() - t0
+            timing["built"] += first
             return _InFlight(k, cap, buf, cnt, base, evals, t_enq=t0)
 
-        def pull_counts(step):
+        def pull_counts(step, children):
             """Block on step's counts + eval units; returns (counts,
             pair-clause evals, bytes pulled)."""
-            counts = np.asarray(jax.device_get(step.cnt))
-            ev = np.asarray(jax.device_get(step.evals))
+            t0 = time.perf_counter()
+            with tracer.annotate("wait_counts"):
+                counts = np.asarray(jax.device_get(step.cnt))
+                ev = np.asarray(jax.device_get(step.evals))
+            if tracer:
+                children.append({"name": "wait_counts", "t0": t0,
+                                 "t1": time.perf_counter()})
             return counts, int(ev.sum()) * unit_pairs, counts.nbytes + ev.nbytes
+
+        def fetch(step, counts):
+            """The step's global bases (checked against the counts) and
+            each device's candidate rows, host-side; returns (the non-empty
+            shards' rows in device order, bytes kept, bytes copied)."""
+            bases = np.asarray(jax.device_get(step.base))
+            expect = np.cumsum(counts) - counts
+            if not np.array_equal(bases, expect):
+                raise RuntimeError(
+                    "hierarchical candidate-count prefix-sum disagrees with "
+                    f"host bookkeeping: device bases {bases.tolist()} vs "
+                    f"expected {expect.tolist()}")
+            # each device's first `count` buffer rows, straight off its
+            # shard (no jit dispatch: a jnp slice of the global array would
+            # compile one distributed program per (device, count) pair —
+            # minutes of churn on a 512-device dry-run mesh).  The copy
+            # moves the shard's whole (cap, 2) buffer and keeps the first
+            # `count` rows: O(capacity) bytes a non-empty shard.
+            out, kept, moved = [], bases.nbytes, bases.nbytes
+            for sh in step.buf.addressable_shards:
+                d = (sh.index[0].start or 0) // step.cap
+                take = int(counts[d])
+                if not take:
+                    continue
+                whole = np.asarray(sh.data)
+                kept += whole[:take].nbytes
+                moved += whole.nbytes
+                out.append((d, whole[:take]))
+            return [seg for _, seg in sorted(out, key=lambda t: t[0])], \
+                kept, moved
+
+        def to_pairs(segs) -> list:
+            """Concatenated candidate rows, padding dropped, as tuples."""
+            if not segs:
+                return []
+            arr = np.concatenate(segs, axis=0)
+            arr = arr[(arr[:, 0] < n_l) & (arr[:, 1] < n_r)]
+            return list(zip(arr[:, 0].tolist(), arr[:, 1].tolist()))
 
         depth = self.effective_prefetch_depth
         ring: collections.deque = collections.deque()   # oldest first
@@ -442,80 +529,72 @@ class ShardedEngine(CnfEngine):
             k = step.k
             t_enq = step.t_enq         # first enqueue: the in-flight window
             step_events = step.events  # opens here even across retries
+            # sub-slices of this pull (wait_counts / retry / fetch /
+            # to_pairs) for the trace; None when tracing is off
+            children = [] if tracer else None
             t_pull0 = time.perf_counter()
+            retry_s = 0.0              # re-dispatch: pull_s leaves it out
             bytes_to_host = 0
             conjunct_evals = 0         # includes retry attempts: real work
-            counts, ev, nb = pull_counts(step)
-            conjunct_evals += ev
-            bytes_to_host += nb
-            while (counts > step.cap).any():
-                # overflow: grow only the overflowing shards (>=4x each,
-                # extract.grow_caps); counts are exact true totals, so the
-                # retried step — dispatched at the new per-shard max —
-                # cannot overflow again.  Every in-flight successor in the
-                # ring was built at the stale capacity: invalidate them
-                # all (drop the futures) and re-dispatch them right after
-                # the retry, in order, so the pipeline stays full and no
-                # chunk is ever emitted at a stale size.
-                caps[:] = extract.grow_caps(caps, counts)
-                t_retry0 = time.perf_counter()
-                successors = [s.k for s in ring]
-                if tracer:
-                    step_events.append(
-                        ("overflow", t_retry0,
-                         {"counts_max": int(counts.max()),
-                          "cap": step.cap}))
-                    if successors:
-                        step_events.append(
-                            ("invalidate", t_retry0, {"steps": successors}))
-                ring.clear()
-                step = dispatch(k)
-                for kk in successors:
-                    redis = dispatch(kk)
-                    if tracer:
-                        redis.events.append(
-                            ("redispatch", redis.t_enq, {"cap": redis.cap}))
-                    ring.append(redis)
-                t_pull0 += time.perf_counter() - t_retry0   # it's dispatch,
-                counts, ev, nb = pull_counts(step)          # not pull
+            with tracer.annotate("pull"):
+                counts, ev, nb = pull_counts(step, children)
                 conjunct_evals += ev
                 bytes_to_host += nb
-            cap = step.cap
-            bases = np.asarray(jax.device_get(step.base))
-            bytes_to_host += bases.nbytes
-            expect = np.cumsum(counts) - counts
-            if not np.array_equal(bases, expect):
-                raise RuntimeError(
-                    "hierarchical candidate-count prefix-sum disagrees with "
-                    f"host bookkeeping: device bases {bases.tolist()} vs "
-                    f"expected {expect.tolist()}")
+                while (counts > step.cap).any():
+                    # overflow: grow only the overflowing shards (>=4x
+                    # each, extract.grow_caps); counts are exact true
+                    # totals, so the retried step — dispatched at the new
+                    # per-shard max — cannot overflow again.  Every
+                    # in-flight successor in the ring was built at the
+                    # stale capacity: invalidate them all (drop the
+                    # futures) and re-dispatch them right after the retry,
+                    # in order, so the pipeline stays full and no chunk is
+                    # ever emitted at a stale size.
+                    caps[:] = extract.grow_caps(caps, counts)
+                    t_retry0 = time.perf_counter()
+                    successors = [s.k for s in ring]
+                    if tracer:
+                        step_events.append(
+                            ("overflow", t_retry0,
+                             {"counts_max": int(counts.max()),
+                              "cap": step.cap}))
+                        if successors:
+                            step_events.append(
+                                ("invalidate", t_retry0,
+                                 {"steps": successors}))
+                    ring.clear()
+                    with tracer.annotate("retry"):
+                        step = dispatch(k)
+                        for kk in successors:
+                            redis = dispatch(kk)
+                            if tracer:
+                                redis.events.append(
+                                    ("redispatch", redis.t_enq,
+                                     {"cap": redis.cap}))
+                            ring.append(redis)
+                    t_retry1 = time.perf_counter()
+                    retry_s += t_retry1 - t_retry0   # it's dispatch, not pull
+                    if tracer:
+                        children.append({"name": "retry", "t0": t_retry0,
+                                         "t1": t_retry1,
+                                         "attrs": {"cap": step.cap}})
+                    counts, ev, nb = pull_counts(step, children)
+                    conjunct_evals += ev
+                    bytes_to_host += nb
+                cap = step.cap
+                t_fetch0 = time.perf_counter()
+                with tracer.annotate("fetch"):
+                    segs, fetched, moved = fetch(step, counts)
+                bytes_to_host += fetched
+                t_fetch1 = time.perf_counter()
+                with tracer.annotate("to_pairs"):
+                    pairs = to_pairs(segs)
             chunk_h2d = staged.bytes_h2d if k == 0 else 0
             chunk_reshard = staged.bytes_reshard if k == 0 else 0
-            # pull each device's first `count` buffer rows straight off its
-            # shard (no jit dispatch: a jnp slice of the global array would
-            # compile one distributed program per (device, count) pair —
-            # minutes of churn on a 512-device dry-run mesh).  The slice is
-            # the transfer a production DMA would move: O(candidates).
-            out = []
-            for sh in step.buf.addressable_shards:
-                d = (sh.index[0].start or 0) // cap
-                take = int(counts[d])
-                if not take:
-                    continue
-                seg = np.asarray(sh.data)[:take]
-                bytes_to_host += seg.nbytes
-                out.append((d, seg))
-            out = [seg for _, seg in sorted(out, key=lambda t: t[0])]
-            if out:
-                arr = np.concatenate(out, axis=0)
-                keep = (arr[:, 0] < n_l) & (arr[:, 1] < n_r)  # drop padding
-                arr = arr[keep]
-                pairs = list(zip(arr[:, 0].tolist(), arr[:, 1].tolist()))
-            else:
-                pairs = []
             t_pull1 = time.perf_counter()
-            pull_s = t_pull1 - t_pull0
+            pull_s = t_pull1 - t_pull0 - retry_s
             dispatch_s, timing["dispatch"] = timing["dispatch"], 0.0
+            built, timing["built"] = timing["built"], 0
             # overlap accounting: host work done while a successor step was
             # in flight on the device — this pull/filter window, plus the
             # time the consumer held the previous chunk.  Exactly 0 for the
@@ -525,19 +604,27 @@ class ShardedEngine(CnfEngine):
             overlap_s = (pull_s if ring else 0.0) + hold_overlap
             trace = track = None
             if tracer:
+                children += [
+                    {"name": "fetch", "t0": t_fetch0, "t1": t_fetch1,
+                     "attrs": {"bytes": fetched, "bytes_moved": moved}},
+                    {"name": "to_pairs", "t0": t_fetch1, "t1": t_pull1,
+                     "attrs": {"candidates": len(pairs)}}]
                 # the "dispatch" slice is the *in-flight window* (enqueue →
                 # pull-begin): at depth ≥ 2 it contains predecessors' pull
                 # windows — the ring overlap, visible as cross-track slice
                 # overlap in Perfetto; at depth 1 it never does.  The host
                 # enqueue wall itself rides along as ``enqueue_s`` (that is
-                # what reconciles against wall.step2_dispatch_s).
+                # what reconciles against wall.step2_dispatch_s).  The
+                # "pull" slice holds any overflow retry as its child, which
+                # the pull wall (pull_s) leaves out.
                 trace = [
                     {"name": "dispatch", "t0": t_enq, "t1": t_pull0,
                      "attrs": {"enqueue_s": dispatch_s, "cap": cap,
                                "band": k}},
                     {"name": "pull", "t0": t_pull0, "t1": t_pull1,
                      "attrs": {"bytes": bytes_to_host,
-                               "candidates": len(pairs)}},
+                               "candidates": len(pairs)},
+                     "children": children},
                 ]
                 track = f"ring{k % depth}"
             t_yield = time.perf_counter()
@@ -545,6 +632,7 @@ class ShardedEngine(CnfEngine):
                              dispatch_s=dispatch_s, pull_s=pull_s,
                              overlap_s=overlap_s,
                              conjunct_evals=conjunct_evals,
+                             programs_built=built,
                              trace=trace, trace_events=step_events or None,
                              track=track)
             hold = time.perf_counter() - t_yield

@@ -206,6 +206,7 @@ def cnf_join_block(emb_l, emb_r, scal_l, scal_r, clauses, thetas, *,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="fused_cnf_join",
     )(bits, emb_l, emb_r, scal_l, scal_r)
     # (n_r//tr, P, n_l) transposed words -> (n_l, n_r//32) row-major mask
     words = outs[0][:, : tr // 32, :].transpose(2, 0, 1)

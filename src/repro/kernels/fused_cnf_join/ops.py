@@ -125,9 +125,16 @@ def pack_features_device(planes, clauses: Sequence, *, tl: int, tr: int,
     Writes the identical values as the host path (padding is constant
     writes, no arithmetic), so kernel outputs are bit-identical whichever
     path staged the planes.  Assemblies are memoized on the plane set
-    (keyed by used features + padded geometry) so repeated warm queries
-    skip the reshuffle entirely.
+    (keyed by used features + padded geometry): queries that hand over
+    the same plane set again skip the reshuffle; a new plane set, even
+    over the same resident arrays, assembles again.
     """
+    return _pack_device(planes, clauses, tl=tl, tr=tr, lane=lane)[:7]
+
+
+def _pack_device(planes, clauses: Sequence, *, tl: int, tr: int, lane: int):
+    """``pack_features_device``'s tuple plus whether the plane set's
+    ``pack_cache`` already held the assembly."""
     kclauses, vec_ids, scal_ids = _clause_layout(planes, clauses)
     used = sorted({f for c in clauses for f in c})
     n_l = planes[used[0]].data_l.shape[0]
@@ -141,7 +148,7 @@ def pack_features_device(planes, clauses: Sequence, *, tl: int, tr: int,
     key = (tuple(used), pl_n, pr_n, d_pad)
     if cache is not None and key in cache:
         emb_l, emb_r, scal_l, scal_r = cache[key]
-        return emb_l, emb_r, scal_l, scal_r, kclauses, n_l, n_r
+        return emb_l, emb_r, scal_l, scal_r, kclauses, n_l, n_r, True
 
     if vec_ids:
         emb_l = jnp.stack([_pad_embed_device(planes.device_l(f), pl_n, d_pad, "l")
@@ -161,7 +168,7 @@ def pack_features_device(planes, clauses: Sequence, *, tl: int, tr: int,
         scal_r = jnp.full((1, pr_n), -1e9, jnp.float32)
     if cache is not None:
         cache[key] = (emb_l, emb_r, scal_l, scal_r)
-    return emb_l, emb_r, scal_l, scal_r, kclauses, n_l, n_r
+    return emb_l, emb_r, scal_l, scal_r, kclauses, n_l, n_r, False
 
 
 @dataclasses.dataclass(eq=False)
@@ -169,7 +176,10 @@ class StagedPlanes:
     """Device-staged kernel inputs plus the transfer accounting for how
     they got there (``bytes_h2d``: host link; ``bytes_reshard``: device-to-
     device moves to lay planes out on a mesh — the quantity warm sharded
-    serving queries must report as zero, DESIGN.md §4)."""
+    serving queries must report as zero, DESIGN.md §4; ``bytes_staged``:
+    the staged arrays this call built, on the device from resident planes
+    or packed on the host and uploaded, 0 when ``pack_hit``, the plane
+    set's ``pack_cache`` already holding them)."""
     emb_l: object
     emb_r: object
     scal_l: object
@@ -179,6 +189,8 @@ class StagedPlanes:
     n_r: int
     bytes_h2d: int = 0
     bytes_reshard: int = 0
+    bytes_staged: int = 0
+    pack_hit: bool = False
 
     @property
     def arrays(self) -> tuple:
@@ -228,14 +240,17 @@ def stage_planes(feats: Sequence, clauses: Sequence, *, tl: int, tr: int,
     device_puts straight to that layout; the resident path pays a one-time
     device-to-device reshard (``bytes_reshard``) whose result is memoized
     on the plane set's ``pack_cache`` keyed by (geometry, mesh, axes) —
-    repeated warm queries reuse the pre-sharded assembly and report
-    ``bytes_reshard == 0``.
+    repeated queries over the same plane set reuse the pre-sharded
+    assembly and report ``bytes_reshard == 0`` and ``bytes_staged == 0``.
     """
     if hasattr(feats, "device_l") and hasattr(feats, "device_r"):
-        emb_l, emb_r, scal_l, scal_r, kclauses, n_l, n_r = \
-            pack_features_device(feats, clauses, tl=tl, tr=tr, lane=lane)
-        staged = StagedPlanes(emb_l, emb_r, scal_l, scal_r, kclauses,
-                              n_l, n_r)
+        emb_l, emb_r, scal_l, scal_r, kclauses, n_l, n_r, hit = \
+            _pack_device(feats, clauses, tl=tl, tr=tr, lane=lane)
+        staged = StagedPlanes(
+            emb_l, emb_r, scal_l, scal_r, kclauses, n_l, n_r,
+            bytes_staged=0 if hit else sum(
+                int(a.nbytes) for a in (emb_l, emb_r, scal_l, scal_r)),
+            pack_hit=hit)
         if mesh is not None:
             cache = getattr(feats, "pack_cache", None)
             used = tuple(sorted({f for c in clauses for f in c}))
@@ -266,7 +281,7 @@ def stage_planes(feats: Sequence, clauses: Sequence, *, tl: int, tr: int,
         arrays = tuple(jnp.asarray(a)
                        for a in (emb_l, emb_r, scal_l, scal_r))
     return StagedPlanes(arrays[0], arrays[1], arrays[2], arrays[3],
-                        kclauses, n_l, n_r, bytes_h2d=h2d)
+                        kclauses, n_l, n_r, bytes_h2d=h2d, bytes_staged=h2d)
 
 
 def evaluate_corpus(feats: Sequence, clauses: Sequence, thetas,

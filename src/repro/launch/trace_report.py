@@ -19,9 +19,10 @@ Consumes the Perfetto/Chrome trace-event JSON written by
     ``args`` — the flat trace-event format carries it through);
   * reconciliation of span sums against the CostLedger wall summary the
     exporter embedded under the top-level ``"fdj"`` key: Σ pull slices
-    vs ``step2_pull_wall``, Σ dispatch ``enqueue_s`` vs
-    ``step2_dispatch_wall`` — the spans and the ledger measure the same
-    perf_counter reads, so they must agree within ``RECONCILE_TOL``.
+    (less their ``retry`` children) vs ``step2_pull_wall``, Σ dispatch
+    ``enqueue_s`` vs ``step2_dispatch_wall`` — the spans and the ledger
+    measure the same perf_counter reads, so they must agree within
+    ``RECONCILE_TOL``.
 
 ``--check`` validates instead of rendering: obs.export.validate_trace
 (envelope, phases, same-track nesting) plus the reconciliation bound,
@@ -143,8 +144,11 @@ def reconcile(obj, slices) -> list:
         ok = rel <= RECONCILE_TOL or abs(span_sum - ledger) < 1e-3
         checks.append((label, span_sum, ledger, rel, ok))
 
+    # a pull slice holds its overflow retries (re-dispatch, billed to the
+    # dispatch wall), which the pull wall leaves out
     add("Σ pull slices vs step2_pull_wall",
-        sum(s["t1"] - s["t0"] for s in slices if s["name"] == "pull") / 1e6,
+        sum((s["t1"] - s["t0"]) * (-1 if s["name"] == "retry" else 1)
+            for s in slices if s["name"] in ("pull", "retry")) / 1e6,
         "step2_pull_wall")
     add("Σ dispatch enqueue_s vs step2_dispatch_wall",
         sum(s["args"].get("enqueue_s", 0.0)

@@ -1,6 +1,6 @@
 """Observability spine: structured tracing + metrics (DESIGN.md §7).
 
-Zero-dependency. Three pieces:
+Zero-dependency (``Tracer.annotate`` imports ``jax`` lazily). Three pieces:
 
   * ``trace``   — ``Tracer`` span trees (context-manager and retroactive
     recording, cross-thread parents, per-track lanes) with a falsy

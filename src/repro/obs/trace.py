@@ -29,6 +29,13 @@ tracer travels by contextvar (``use_tracer`` / ``current_tracer``), not by
 threading it through every engine signature; threads started inside a
 traced region must capture it (and a parent span) explicitly —
 ``contextvars`` do not cross ``threading.Thread``.
+
+``tracer.annotate(name)`` puts a host interval on the device profiler's
+clock as well: a ``jax.profiler.TraceAnnotation("fdj.<name>")`` (``jax``
+imported on first use), recorded only while a profiler session runs, so a
+device trace can tell what the host was doing in each idle gap.  Callers
+keep their ``perf_counter`` span beside it; ``NullTracer.annotate``
+returns ``NULL_SPAN``.
 """
 
 from __future__ import annotations
@@ -152,6 +159,12 @@ class Tracer:
                 sp.events.append(SpanEvent(nm, ts, dict(at) if at else {}))
         return sp
 
+    def annotate(self, name: str):
+        """A context manager marking the block as ``fdj.<name>`` on the
+        profiler's clock (a no-op unless a profiler session is running)."""
+        from jax.profiler import TraceAnnotation
+        return TraceAnnotation(f"fdj.{name}")
+
     def event(self, name: str, ts: Optional[float] = None, **attrs) -> None:
         """Mark an instant on this thread's innermost open span (dropped
         when no span is open — events always belong to a span)."""
@@ -223,6 +236,9 @@ class NullTracer:
 
     def record_span(self, name, t0, t1, *, parent=None, track=None,
                     attrs=None, events=None):
+        return NULL_SPAN
+
+    def annotate(self, name):
         return NULL_SPAN
 
     def event(self, name, ts=None, **attrs):

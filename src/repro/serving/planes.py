@@ -127,7 +127,9 @@ class DevicePlaneSet(Sequence):
     assemble the kernel layout on device (zero H2D); the numpy engine and
     ``corpus_shape`` use the Sequence-of-FeatureData protocol unchanged.
     ``pack_cache`` memoizes assembled kernel layouts per padded geometry so
-    repeated warm queries skip even the on-device reshuffle.
+    repeated warm queries that get this same plane set back (the store's
+    ``provide`` while nothing changed) skip even the on-device reshuffle;
+    a new plane set over the same resident arrays assembles again.
 
     ``mesh`` (inherited from the store) is the sharded engine's default
     execution mesh for queries over this plane set: the engine lays the

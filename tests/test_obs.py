@@ -86,14 +86,18 @@ def test_null_tracer_is_inert_and_guard_allocates_nothing():
 
     def band_loop(n):
         # the instrumented hot-loop shape: one truthiness branch; the
-        # attr dict is never built when tracing is off
+        # attr dict is never built when tracing is off; the profiler
+        # annotation is the shared NULL_SPAN
         acc = 0
         for i in range(n):
+            with tracer.annotate("pull"):
+                acc += i
             if tracer:
                 tracer.record_span("band_step", 0.0, 1.0,
                                    attrs={"candidates": i})
-            acc += i
         return acc
+
+    assert tracer.annotate("pull") is NULL_SPAN
 
     band_loop(100)                             # warm bytecode/caches
     tracemalloc.start()
@@ -335,3 +339,128 @@ def test_join_service_metrics_always_derive_lifetime_ledger():
     assert ok, why
     assert svc.metrics.value("serve.plan_hits") >= 1.0
     assert svc.metrics.histogram("serve.query_wall_s").count == 3
+
+
+# --- step-② ring spans, staging counters, profiler annotations --------------
+
+def _by_parent(spans):
+    kids = {}
+    for sp in spans:
+        kids.setdefault(sp.parent_id, []).append(sp)
+    return kids
+
+
+def test_sharded_pull_splits_into_children():
+    ds = synth.citations(n_docs=101, seed=9)   # 4 R bands at r_chunk=32
+    feats, clauses, thetas = _materialized_cnf(ds)
+    tr = Tracer()
+    with use_tracer(tr):
+        res = get_engine("sharded", tl=32, tr=32, r_chunk=32,
+                         prefetch_depth=2).evaluate(feats, clauses, thetas)
+    spans = tr.spans()
+    kids = _by_parent(spans)
+    pulls = [s for s in spans if s.name == "pull"]
+    assert len(pulls) == 4
+    for pull in pulls:
+        children = kids[pull.span_id]
+        assert [c.name for c in children] == ["wait_counts", "fetch",
+                                              "to_pairs"]
+        assert sum(c.duration_s for c in children) <= pull.duration_s
+        for c in children:
+            assert pull.t0 <= c.t0 <= c.t1 <= pull.t1
+            assert c.track == pull.track
+        fetch = children[1]
+        # the kept rows are 8 B a candidate plus the bases; the copy moves
+        # each non-empty shard's whole buffer
+        assert fetch.attrs["bytes_moved"] >= fetch.attrs["bytes"]
+        assert children[2].attrs["candidates"] == pull.attrs["candidates"]
+    steps = [s for s in spans if s.name.startswith("band_step[")]
+    assert sum(s.attrs["candidates"] for s in steps) == len(res.candidates)
+    assert all("programs_built" in s.attrs for s in steps)
+    for s in steps:
+        names = [c.name for c in kids[s.span_id]]
+        assert names == ["dispatch", "pull", "sort_pairs"]
+    assert validate_trace(to_trace_events(tr)) == []
+
+
+def test_stage_planes_counts_staged_bytes_and_cache_hits():
+    import jax.numpy as jnp
+
+    from repro.kernels.fused_cnf_join import ops as cnf_ops
+    from repro.serving.planes import DevicePlaneSet
+
+    ds = synth.police_records(n_incidents=20, reports_per_incident=2, seed=7)
+    feats, clauses, thetas = _materialized_cnf(ds)
+
+    def plane_set():
+        return DevicePlaneSet(feats, [jnp.asarray(f.data_l) for f in feats],
+                              [jnp.asarray(f.data_r) for f in feats])
+
+    planes = plane_set()
+    eng = get_engine("sharded", **_OPTS["sharded"])
+    tr = Tracer()
+    with use_tracer(tr):
+        eng.evaluate(planes, clauses, thetas)
+        eng.evaluate(planes, clauses, thetas)      # same plane set: a hit
+    miss, hit = [s.attrs for s in tr.spans() if s.name == "stage_planes"]
+    staged = cnf_ops.stage_planes(plane_set(), clauses, tl=32, tr=64)
+    assert miss["bytes_staged"] == sum(a.nbytes for a in staged.arrays) > 0
+    assert miss["pack_hit"] is False and staged.pack_hit is False
+    assert hit["bytes_staged"] == 0 and hit["pack_hit"] is True
+    assert miss["bytes_h2d"] == hit["bytes_h2d"] == 0
+    # the host path uploads what it stages
+    host = cnf_ops.stage_planes(feats, clauses, tl=32, tr=64)
+    assert host.bytes_staged == host.bytes_h2d > 0 and not host.pack_hit
+
+
+def test_annotations_reach_the_profiler_host_plane(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    ds = synth.citations(n_docs=101, seed=9)
+    feats, clauses, thetas = _materialized_cnf(ds)
+    eng = get_engine("sharded", tl=32, tr=32, r_chunk=32)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with use_tracer(Tracer()):
+            eng.evaluate(feats, clauses, thetas)
+    finally:
+        jax.profiler.stop_trace()
+    pb, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(pb).planes
+             if not plane.name.startswith("/device:")
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("fdj.")}
+    assert names >= {"fdj.stage_planes", "fdj.pull", "fdj.wait_counts",
+                     "fdj.fetch", "fdj.to_pairs", "fdj.sort_pairs"}
+    assert names & {"fdj.enqueue", "fdj.compile"}
+
+
+def test_overflow_retry_nests_in_pull_and_reconciles():
+    from repro.core.featurize import FeaturizationSpec
+
+    n_l, n_r = 33, 128                         # matches only in R band 2
+    spec = FeaturizationSpec("name", "", "word_overlap", "llm", "name")
+    feats = [vectorize(spec, ["same text"] * n_l,
+                       ["zzz yyy"] * 64 + ["same text"] * 32
+                       + ["zzz yyy"] * 32)]
+    eng = get_engine("sharded", tl=32, tr=32, r_chunk=32, capacity=1,
+                     prefetch_depth=2)
+    tr = Tracer()
+    with use_tracer(tr):
+        res = eng.evaluate(feats, [[0]], [0.25])
+    spans = tr.spans()
+    retries = [s for s in spans if s.name == "retry"]
+    assert retries
+    by_id = {s.span_id: s for s in spans}
+    for r in retries:
+        pull = by_id[r.parent_id]
+        assert pull.name == "pull" and pull.t0 <= r.t0 <= r.t1 <= pull.t1
+    obj = to_trace_events(tr)
+    led = CostLedger()
+    led.record_engine_stats(res.stats)
+    led.record_walls(res.stats.wall_s, 0.0, 0.0)
+    obj["fdj"] = {"wall_summary": led.wall_summary()}
+    assert trace_report.check(obj) == [], trace_report.check(obj)
