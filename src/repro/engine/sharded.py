@@ -540,6 +540,8 @@ class ShardedEngine(CnfEngine):
                 counts, ev, nb = pull_counts(step, children)
                 conjunct_evals += ev
                 bytes_to_host += nb
+                # extraction's loop trips, from the counts (no device work)
+                blocks = extract.extract_blocks(counts, step.cap)
                 while (counts > step.cap).any():
                     # overflow: grow only the overflowing shards (>=4x
                     # each, extract.grow_caps); counts are exact true
@@ -581,6 +583,7 @@ class ShardedEngine(CnfEngine):
                     counts, ev, nb = pull_counts(step, children)
                     conjunct_evals += ev
                     bytes_to_host += nb
+                    blocks += extract.extract_blocks(counts, step.cap)
                 cap = step.cap
                 t_fetch0 = time.perf_counter()
                 with tracer.annotate("fetch"):
@@ -623,7 +626,8 @@ class ShardedEngine(CnfEngine):
                                "band": k}},
                     {"name": "pull", "t0": t_pull0, "t1": t_pull1,
                      "attrs": {"bytes": bytes_to_host,
-                               "candidates": len(pairs)},
+                               "candidates": len(pairs),
+                               "extract_blocks": blocks},
                      "children": children},
                 ]
                 track = f"ring{k % depth}"

@@ -8,7 +8,8 @@ Consumes the Perfetto/Chrome trace-event JSON written by
 (obs.export) and prints what a viewer would show, for terminals and CI:
 
   * per-category slice totals (``band_step[7]`` aggregates as
-    ``band_step``);
+    ``band_step``), and the on-device extraction's loop trips summed over
+    the pull slices' ``extract_blocks``;
   * an ASCII timeline, one row per track (tid), so prefetch-ring overlap
     — ``band_step[k+1]``'s in-flight dispatch window riding over
     ``band_step[k]``'s pull — is visible without a browser;
@@ -108,6 +109,14 @@ def ring_overlap_s(slices) -> float:
     return tot / 1e6
 
 
+def extract_blocks(slices) -> tuple:
+    """(Σ ``extract_blocks``, pull slices carrying it): the loop trips
+    the band steps' candidate extraction made, over the trace."""
+    got = [s["args"]["extract_blocks"] for s in slices
+           if s["name"] == "pull" and "extract_blocks" in s["args"]]
+    return sum(got), len(got)
+
+
 def critical_path(slices) -> list:
     """Longest root, then its longest child, recursively."""
     by_id = {s["args"]["span_id"]: s for s in slices
@@ -180,6 +189,9 @@ def report(obj) -> str:
     for cat, (n, tot, mx) in _categories(slices):
         lines.append(f"  {cat:<16} {n:>5} {tot / 1e6:>9.4f} "
                      f"{mx / 1e3:>9.2f}")
+    blocks, pulls = extract_blocks(slices)
+    if pulls:
+        lines.append(f"  extract_blocks: {blocks} over {pulls} pulls")
     lines.append("")
     lines.extend(_timeline(slices, tracks))
     lines.append("")

@@ -439,6 +439,9 @@ def test_annotations_reach_the_profiler_host_plane(tmp_path):
 
 
 def test_overflow_retry_nests_in_pull_and_reconciles():
+    """An overflow retry nests in its pull, the trace reconciles, and each
+    pull's ``extract_blocks`` counts the extraction's loop trips, the
+    retried attempt's included; trace_report totals them."""
     from repro.core.featurize import FeaturizationSpec
 
     n_l, n_r = 33, 128                         # matches only in R band 2
@@ -458,9 +461,16 @@ def test_overflow_retry_nests_in_pull_and_reconciles():
     for r in retries:
         pull = by_id[r.parent_id]
         assert pull.name == "pull" and pull.t0 <= r.t0 <= r.t1 <= pull.t1
+    # one device: an empty band makes no trip; the overflowed attempt at
+    # capacity 1 makes one, its retry one more
+    pulls = [s for s in spans if s.name == "pull"]
+    assert [p.attrs["extract_blocks"] for p in pulls] == [0, 0, 2, 0]
     obj = to_trace_events(tr)
+    assert trace_report.extract_blocks(trace_report._slices(obj)) == (2, 4)
+    assert "extract_blocks: 2 over 4 pulls" in trace_report.report(obj)
     led = CostLedger()
     led.record_engine_stats(res.stats)
     led.record_walls(res.stats.wall_s, 0.0, 0.0)
     obj["fdj"] = {"wall_summary": led.wall_summary()}
     assert trace_report.check(obj) == [], trace_report.check(obj)
+
