@@ -19,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 @dataclasses.dataclass
@@ -53,6 +54,14 @@ def _band(ls, rs, *, clauses, thetas):
     return jnp.packbits(ok, axis=1)
 
 
+def _whole(x):
+    """``x`` whole on every device of its mesh where it is split by rows,
+    so that each band of it meets every shard of L."""
+    if isinstance(x.sharding, NamedSharding):
+        return jax.device_put(x, NamedSharding(x.sharding.mesh, P()))
+    return x
+
+
 class ControlEngine:
     """Yields one chunk of candidates per ``r_chunk`` R columns."""
 
@@ -62,7 +71,7 @@ class ControlEngine:
     def evaluate_stream(self, planes, clauses, thetas):
         n = len(planes)
         ls = tuple(planes.device_l(f) for f in range(n))
-        rs = tuple(planes.device_r(f) for f in range(n))
+        rs = tuple(_whole(planes.device_r(f)) for f in range(n))
         key = dict(clauses=tuple(tuple(c) for c in clauses),
                    thetas=tuple(float(t) for t in thetas))
         for k, c0 in enumerate(range(0, rs[0].shape[0], self.r_chunk)):

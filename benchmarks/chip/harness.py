@@ -6,15 +6,17 @@ drained chunk by chunk until every candidate is on the host as the engine
 yields it: plane staging, the band-step program (the fused CNF kernel and
 on-device extraction), the ring's pulls and the host's conversion to pairs.
 
-* Set-up makes the resident planes from the seed on the device and a
-  probe's batches on the host (``traffic.py``), and runs the cell's own
+* Set-up builds the cell's mesh over its ``chips`` devices, makes the
+  resident planes from the seed on the device, sharded over that mesh, and
+  a probe's batches on the host (``traffic.py``), and runs the cell's own
   shapes once (a band step of a sweep, or whole probe queries), so that
   every program the window runs is compiled.  Compilations inside the
   window are counted.
-* Resident planes reach the engine as a ``serving.planes.DevicePlaneSet``,
-  the serving store's residency path: staging assembles them on the device
-  and moves no resident byte host-to-device.  A probe's new rows are put on
-  the device inside the query, as new records' planes would be.
+* Resident planes reach the engine as a ``serving.planes.DevicePlaneSet``
+  on the cell's mesh, the serving store's residency path: staging
+  assembles them on the device and moves no resident byte host-to-device.
+  A probe's new rows are put on the device inside the query, as new
+  records' planes would be.
 * The window closes with the first query that ends past ``seconds``: every
   query in it runs to its last candidate, so all the work sent counts, over
   all the time it took.  The mix sets the ring depth (``prefetch_depth``):
@@ -23,10 +25,10 @@ on-device extraction), the ring's pulls and the host's conversion to pairs.
 * Checked queries keep their candidates as arrays, not as the engine's
   tuples, so the window's garbage collections stay as short as the
   program's own.
-* After the window the device's peak memory is read, the program's device
-  state is freed and the resident planes are fetched to the host; then the
-  checked queries are compared with the plain reference (``reference.py``)
-  on the host.
+* After the window each of the cell's devices' peak memory is read, the
+  program's device state is freed and the resident rows the check reads
+  are fetched to the host, shard by shard; then the checked queries are
+  compared with the plain reference (``reference.py``) on the host.
 """
 
 from __future__ import annotations
@@ -41,7 +43,7 @@ import time
 
 import numpy as np
 
-from traffic import Traffic
+from traffic import Traffic, cell_mesh
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
@@ -104,6 +106,7 @@ class Context:
     device_kind: str
     trace: dict | None             # devtrace.extract of the window
     spans: list                    # the program's Tracer spans
+    chips: int                     # devices of the cell's mesh
 
 
 class _Compiles:
@@ -117,16 +120,24 @@ class _Compiles:
             self.n += 1
 
 
-def _plane_set(kinds, dev_l, dev_r):
-    """The planes as the serving store hands them to the engine; the
-    engine reads only the shapes of the features' own arrays."""
+def device_memory(devices) -> list:
+    """Each device's peak bytes in use so far (0 where the backend does not
+    report it)."""
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devices]
+
+
+def _plane_set(kinds, dev_l, dev_r, mesh):
+    """The planes as the serving store hands them to the engine, on the
+    cell's mesh; the engine reads only the shapes of the features' own
+    arrays."""
     from repro.core.featurize import FeatureData, FeaturizationSpec
     from repro.serving.planes import DevicePlaneSet
     feats = [FeatureData(FeaturizationSpec(
         f"f{i}", "", "semantic" if k == "embed" else "arithmetic", "code",
         f"f{i}"), k, dl, dr) for i, (k, dl, dr) in
         enumerate(zip(kinds, dev_l, dev_r))]
-    return DevicePlaneSet(feats, dev_l, dev_r)
+    return DevicePlaneSet(feats, dev_l, dev_r, mesh=mesh)
 
 
 def run_cell(bench: dict, workload: str, seed: int, seconds: float,
@@ -135,7 +146,8 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
              on_trace=None) -> dict:
     """One run; returns the result object ``run.py`` prints.  ``engine``
     replaces the system under test (the control, a planted fault);
-    ``overrides`` replaces config and mix keys (small sizes in tests);
+    ``overrides`` replaces cell, config and mix keys (small sizes and
+    device counts in tests);
     ``on_trace`` is handed the captured trace of a traced run."""
     import jax
     import jax.monitoring
@@ -147,23 +159,26 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
 
     t_start = time.perf_counter() if t_start is None else t_start
     log = log or (lambda msg: print(msg, file=sys.stderr, flush=True))
-    _, config, mix = load_cell(bench, workload)
+    cell, config, mix = load_cell(bench, workload)
+    cell = dict(cell)
     for key, value in (overrides or {}).items():
-        (mix if key in mix else config)[key] = value
+        (cell if key in cell else mix if key in mix else config)[key] = value
     if engine is None:
         from repro.engine.sharded import ShardedEngine
         engine = ShardedEngine(prefetch_depth=int(mix["prefetch_depth"]))
     r_chunk = getattr(engine, "r_chunk", None) or 4 * engine.tr
 
+    chips = int(cell["chips"])
+    mesh = cell_mesh(chips)
     t_planes = time.perf_counter()
-    traffic = Traffic(config, mix, seed)
+    traffic = Traffic(config, mix, seed, mesh)
     jax.block_until_ready(traffic.resident)
     t_planes = time.perf_counter() - t_planes
     clauses, thetas, kinds = traffic.clauses, traffic.thetas, traffic.kinds
     resident = traffic.resident
     sweep_set = None
     if traffic.kind == "sweep":
-        sweep_set = _plane_set(kinds, resident["l"], resident["r"])
+        sweep_set = _plane_set(kinds, resident["l"], resident["r"], mesh)
 
     def plane_set(k: int, tracer=None):
         if sweep_set is not None:
@@ -174,7 +189,7 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
             tracer.record_span("upload_batch", t0, time.perf_counter())
         dev_l = dev if traffic.new_side == "l" else resident["l"]
         dev_r = dev if traffic.new_side == "r" else resident["r"]
-        return _plane_set(kinds, dev_l, dev_r)
+        return _plane_set(kinds, dev_l, dev_r, mesh)
 
     n_l, n_r = traffic.n_l, traffic.n_r
     steps_per_query = -(-n_r // r_chunk)
@@ -247,12 +262,16 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
     gc.unfreeze()
 
     dev0 = jax.devices()[0]
-    memory = dev0.memory_stats() or {}
+    peaks = device_memory(mesh.devices.flat)
     device = {"platform": dev0.platform, "kind": dev0.device_kind,
               "count": len(jax.devices()),
-              "memory_peak_bytes": int(memory.get("peak_bytes_in_use", 0))}
+              "memory_peak_bytes": max(peaks),
+              "memory_peak_bytes_per_device": peaks}
     del sweep_set, resident
-    traffic.fetch()
+    checked = [(q, min(n_r, max(i for i, _ in q.chunks) * r_chunk
+                       + r_chunk))
+               for q in queries if q.chunks is not None and q.steps]
+    traffic.fetch(max((n for _, n in checked), default=0))
     gc.collect()
     log(f"window: {len(queries)} queries, {len(pairs_done)} band steps, "
         f"{n_compiles} compilations, {resident_h2d} resident bytes "
@@ -262,7 +281,8 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
     ctx = Context(queries, (t_window, t_last), pairs_done, setup_s,
                   kernel_work.band_step_work(n_l, min(r_chunk, n_r),
                                              config["features"], clauses),
-                  device["kind"], captured, tracer.spans() if tracer else [])
+                  device["kind"], captured, tracer.spans() if tracer else [],
+                  chips)
     metrics = {}
     for entry in cell_metrics(bench, workload, trace):
         value = load_reader(entry["name"])(ctx)
@@ -282,18 +302,14 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
 
     # -- the check ------------------------------------------------------------------
     limits = config["limits"]
-    gap, dups, failed, checked = 0.0, 0, 0, 0
-    for q in queries:
-        if q.chunks is None or not q.steps:
-            continue
+    gap, dups, failed = 0.0, 0, 0
+    for q, n_cols in checked:
         host_l, host_r = traffic.planes(q.k)
         pairs = np.concatenate([c for _, c in q.chunks])
-        n_cols = min(n_r, max(i for i, _ in q.chunks) * r_chunk + r_chunk)
         if sorted(i for i, _ in q.chunks) != list(range(q.steps)):
             raise RuntimeError(f"query {q.k}: band steps out of order")
         got = reference.compare(pairs, host_l, host_r, clauses, thetas,
-                                traffic.check_rows, n_cols)
-        checked += 1
+                                traffic.check_rows, n_cols, n_l)
         gap, dups = max(gap, got["gap"]), dups + got["duplicates"]
         if got["gap"] > limits["gap"] or got["duplicates"] > \
                 limits["duplicates"]:
@@ -302,7 +318,7 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
             log(f"check q{q.k}: {got}")
     if not checked:
         raise RuntimeError("no query was checked")
-    log(f"checked {checked} queries, {failed} over a limit")
+    log(f"checked {len(checked)} queries, {failed} over a limit")
     out = {"correct": failed == 0, "attempted": len(queries),
            "failed": failed, "metrics": metrics, "device": device}
     if breakdown is not None:

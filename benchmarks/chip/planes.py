@@ -6,14 +6,27 @@ quantile, the missing-value rate, which side's rows are planted near a row of
 the other side and how many, and the share of planted rows that sit just
 inside clause 0's threshold ("boundary" rows, below).
 
-Planes are made on the device, one jitted call a side, from a key drawn from
-the seed, and come out in the layout the engines take (``core.featurize``):
+Planes are made on the device from keys drawn from the seed, and come out in
+the layout the engines take (``core.featurize``):
 an embed row of width ``D`` is a unit vector with two marker columns
 appended, ``[e, m, 1]`` on L and ``[e, 1, m]`` on R with ``m = -2`` where the
 value is missing, so a missing value is at distance 1 from everything; a
 missing scalar is ``+1e9`` on L and ``-1e9`` on R.  The generator uses no
 matrix product, whose precision differs by backend: only elementwise
 arithmetic and row sums.
+
+A resident side is made one shard at a time, each on its own device of the
+cell's mesh, in blocks of at most ``BLOCK_ROWS`` rows (``Deployment.side``):
+a block is drawn raw, encoded into its shard's planes and let go, so a
+device holds its shard's planes and one block's draw, never a whole side
+raw and encoded.  The shards are then one ``jax.Array`` whose rows are split
+over the mesh's ``"data"`` axis, as the engine splits L.  Block ``j`` of
+shard ``s`` draws from ``(seed, *tags, s, j)``, and block 0 of shard 0 from
+``(seed, *tags)``: a side of one block on one chip is the single draw it
+always was, and a row's values do not depend on the device that made it.
+Rows planted near rows of the other side name their partners there
+(``Deployment.pick``); ``Deployment.side`` returns those partner rows,
+gathered from each raw block as it is made, not from a whole raw side.
 
 Thresholds are calibrated once per deployment, on rows drawn from a fixed
 calibration key and not from the run's seed: the same seed-independent
@@ -46,6 +59,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+BLOCK_ROWS = 131_072           # rows a resident side is drawn in at a time
+                               # (read when drawn: tests set it smaller)
 CALIBRATION_ROWS = 8192        # rows a side the thresholds are calibrated on
 CALIBRATION_PAIRS = 200_000    # random pairs the quantiles are read from
 CALIBRATION_TAG = 7
@@ -123,6 +138,20 @@ class Rows:
         return list(_encode(tuple(self.values), tuple(self.missing), side))
 
 
+@dataclasses.dataclass(frozen=True)
+class Draw:
+    """One call of the generator: ``n`` rows from key ``key``, made on
+    ``device``."""
+    key: object
+    n: int
+    device: object
+
+
+def draw(seed: int, tags: tuple, n: int, device) -> Draw:
+    """``n`` rows from the stream ``(seed, *tags)``, made on ``device``."""
+    return Draw(jax.device_put(key(seed, *tags), device), int(n), device)
+
+
 @functools.partial(jax.jit, static_argnums=2)
 def _encode(values, missing, side):
     out = []
@@ -136,6 +165,43 @@ def _encode(values, missing, side):
         else:
             out.append(jnp.where(miss, 1e9 if side == "l" else -1e9, v))
     return tuple(out)
+
+
+@functools.partial(jax.jit, static_argnums=4, donate_argnums=0)
+def _encode_into(planes, values, missing, row0, side):
+    """``planes`` with the rows from ``row0`` on replaced by a block's,
+    encoded, in place."""
+    return tuple(lax.dynamic_update_slice_in_dim(p, e, row0, axis=0)
+                 for p, e in zip(planes, _encode(values, missing, side)))
+
+
+@jax.jit
+def _take(block, pi, row0):
+    """Raw rows of indices ``pi`` from ``block`` (values, missing), the
+    side's rows from ``row0`` on, and which of ``pi`` lie in it."""
+    n = block[1][0].shape[0]
+    inside = (pi >= row0) & (pi < row0 + n)
+    i = jnp.clip(pi - row0, 0, n - 1)
+    return jax.tree.map(lambda b: b[i], block), inside
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _merge(rows, taken):
+    """Raw rows ``rows`` with ``taken``'s (``_take``) where they lie in
+    its block, in place."""
+    new, inside = taken
+    return jax.tree.map(
+        lambda old, b: jnp.where(
+            inside.reshape((-1,) + (1,) * (old.ndim - 1)), b, old),
+        rows, new)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _pick(k, n, n_partner, n_feats):
+    """Each of ``n`` rows' partner among ``n_partner``, drawn as ``_rows``
+    splits its key."""
+    return jax.random.randint(jax.random.split(k, 5 + n_feats)[1], (n,), 0,
+                              n_partner)
 
 
 class Deployment:
@@ -161,16 +227,87 @@ class Deployment:
 
     # -- generation -----------------------------------------------------------
 
-    def rows(self, k, n: int, partner: "Rows | None" = None,
-             planted_share: float = 0.0) -> Rows:
-        """``n`` rows from key ``k``; with ``partner`` (rows of the other
-        side) a ``planted_share`` of them lie near a random partner row, and
-        a ``boundary_share`` of all rows are boundary rows of such a pair."""
-        pv = tuple(partner.values) if partner is not None else None
-        pm = tuple(partner.missing) if partner is not None else None
-        values, missing = _rows(k, pv, pm, float(planted_share),
-                                float(self.thetas[0]), int(n), self._static)
+    @staticmethod
+    def draws(seed: int, tags: tuple, n: int, devices: list) -> list:
+        """A side of ``n`` rows split evenly over ``devices``: per shard,
+        the draws of its blocks of at most ``BLOCK_ROWS`` rows, keyed as
+        the module docstring says."""
+        if n % len(devices):
+            raise ValueError(f"{n} rows do not split evenly over "
+                             f"{len(devices)} chips")
+        per = n // len(devices)
+        return [[draw(seed, (*tags, s, j) if s or j else tags,
+                      min(BLOCK_ROWS, per - r0), dev)
+                 for j, r0 in enumerate(range(0, per, BLOCK_ROWS))]
+                for s, dev in enumerate(devices)]
+
+    def pick(self, d: Draw, n_partner: int):
+        """Each of ``d``'s rows' partner among the other side's
+        ``n_partner`` rows: row indices, on ``d``'s device."""
+        return _pick(d.key, d.n, int(n_partner), len(self.features))
+
+    def rows(self, d: Draw, planted_share: float = 0.0,
+             near: Rows | None = None) -> Rows:
+        """``d``'s rows; with ``near`` (the raw partner rows it picked) a
+        ``planted_share`` of them lie near their partner, and a
+        ``boundary_share`` of all rows are boundary rows of such a pair."""
+        near = (None, None) if near is None else \
+            (tuple(near.values), tuple(near.missing))
+        values, missing = _rows(d.key, *near, float(planted_share),
+                                float(self.thetas[0]), d.n, self._static)
         return Rows(list(values), list(missing))
+
+    def _zeros(self, n: int, device, extra: int) -> tuple:
+        """A float32 array a feature of ``n`` rows, embeds ``extra``
+        columns wider than their width, and (``extra`` 0) a bool missing
+        mask a feature, made on ``device`` itself (``jnp.zeros(device=)``
+        makes them on the default device and copies)."""
+        feats = self._static[0]
+        with jax.default_device(device):
+            planes = tuple(jnp.zeros((n, d + extra) if kind == "embed"
+                                     else (n,), jnp.float32)
+                           for kind, d, _ in feats)
+            return planes if extra else \
+                (planes, tuple(jnp.zeros((n,), bool) for _ in feats))
+
+    def side(self, shards: list, side: str, mesh, partners: list = (),
+             near: list | None = None, planted_share: float = 0.0) -> tuple:
+        """The side drawn as ``shards`` (``draws``), and rows of it.
+
+        Returns its planes, one ``jax.Array`` a feature with its rows split
+        over ``mesh``'s ``"data"`` axis (``P("data", None)`` embeds,
+        ``P("data")`` scalars), and for each array of row indices in
+        ``partners`` (``pick``) those rows raw, on that array's device.
+        ``near`` holds each draw's partner rows on the other side (one
+        ``Rows`` a draw, in order), where a ``planted_share`` of its rows
+        are planted.
+        """
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        near = iter(near or ())
+        # each array's rows, on its device, gathered from every block
+        homes = [next(iter(pi.devices())) for pi in partners]
+        got = [self._zeros(pi.shape[0], home, 0)
+               for pi, home in zip(partners, homes)]
+        done, row0 = [], 0
+        for blocks in shards:
+            planes, at = self._zeros(sum(d.n for d in blocks),
+                                     blocks[0].device, 2), 0
+            for d in blocks:
+                raw = self.rows(d, planted_share, next(near, None))
+                block = (tuple(raw.values), tuple(raw.missing))
+                for k, (pi, home) in enumerate(zip(partners, homes)):
+                    got[k] = _merge(got[k], jax.device_put(_take(
+                        block, jax.device_put(pi, d.device), row0), home))
+                planes = _encode_into(planes, *block, at, side)
+                del raw, block
+                at, row0 = at + d.n, row0 + d.n
+            done.append(planes)
+        return [jax.make_array_from_single_device_arrays(
+            (row0,) + parts[0].shape[1:],
+            NamedSharding(mesh, P("data", None) if parts[0].ndim == 2
+                          else P("data")), list(parts))
+            for parts in zip(*done)], [Rows(list(v), list(m))
+                                       for v, m in got]
 
 
 @functools.cache
@@ -211,24 +348,23 @@ def _pair_dist(a, b, i, j):
 
 
 @functools.partial(jax.jit, static_argnums=(5, 6))
-def _rows(k, partner_values, partner_missing, planted_share, theta0, n,
-          static):
-    """The jitted body of ``Deployment.rows``."""
+def _rows(k, near_values, near_missing, planted_share, theta0, n, static):
+    """The jitted body of ``Deployment.rows``: ``near_*`` are the raw
+    partner rows, one a row, or ``None``."""
     feats, fb, noise_e, noise_s, missing_rate, boundary_share, margin = static
-    k_plant, k_pick, k_miss, k_want, k_bound, *k_feat = \
+    # the second key picks the partners (``_pick``)
+    k_plant, _, k_miss, k_want, k_bound, *k_feat = \
         jax.random.split(k, 5 + len(feats))
-    has_partner = partner_values is not None
+    has_partner = near_values is not None
     if has_partner:
         plant = jax.random.uniform(k_plant, (n,)) < planted_share
-        pi = jax.random.randint(k_pick, (n,), 0,
-                                partner_missing[0].shape[0])
     values = []
     for fi, (kind, d, span) in enumerate(feats):
         k1, k2, k3 = jax.random.split(k_feat[fi], 3)
         if kind == "embed":
             v = _unit(jax.random.normal(k1, (n, d), jnp.float32))
             if has_partner:
-                near = partner_values[fi][pi] + noise_e / np.sqrt(d) * \
+                near = near_values[fi] + noise_e / np.sqrt(d) * \
                     jax.random.normal(k2, (n, d), jnp.float32)
                 v = jnp.where(plant[:, None], _unit(near), v)
             if fi == fb:
@@ -238,17 +374,17 @@ def _rows(k, partner_values, partner_missing, planted_share, theta0, n,
         else:
             v = jax.random.uniform(k1, (n,), jnp.float32, 0.0, span)
             if has_partner:
-                near = partner_values[fi][pi] + noise_s * \
+                near = near_values[fi] + noise_s * \
                     jax.random.normal(k2, (n,), jnp.float32)
                 v = jnp.where(plant, near, v)
         values.append(v)
     missing = [jax.random.uniform(km, (n,)) < missing_rate
                for km in jax.random.split(k_miss, len(feats))]
     if has_partner:
-        partner_ok = ~functools.reduce(jnp.logical_or, partner_missing)
-        boundary = plant & partner_ok[pi] & \
+        partner_ok = ~functools.reduce(jnp.logical_or, near_missing)
+        boundary = plant & partner_ok & \
             (jax.random.uniform(k_want, (n,)) < boundary_share)
-        rows = _boundary_rows(partner_values[fb][pi], k_bound, theta0, margin)
+        rows = _boundary_rows(near_values[fb], k_bound, theta0, margin)
         values[fb] = jnp.where(boundary[:, None], rows, values[fb])
         missing = [m & ~boundary for m in missing]
     return tuple(values), tuple(missing)

@@ -1,11 +1,12 @@
 """Plain reference for step ②, and the comparison that decides ``correct``.
 
 The reference evaluates the CNF straight from its definition on the same
-planes the program was given (the benchmark made them; nothing the program
-made is read): per feature the distance ``clip(0.5 - 0.5 a.b, 0, 1)`` for
-embeds and ``clip(|x - y|, 0, 1)`` for scalars, per clause the least
-distance over its features, and a pair is a candidate when every clause's
-distance is at most its threshold.  It computes in float32 and computes
+planes the program was given (the benchmark made them and copies back only
+the rows it checks; nothing the program made is read): per feature the
+distance ``clip(0.5 - 0.5 a.b, 0, 1)`` for embeds and
+``clip(|x - y|, 0, 1)`` for scalars, per clause the least distance over
+its features, and a pair is a candidate when every clause's distance is
+at most its threshold.  It computes in float32 and computes
 again in float64 every pair that float32 places within ``NEAR`` of a
 threshold, so each decision is the float64 one.  Its score for a pair is
 ``max over clauses (clause distance - threshold)``: a candidate scores
@@ -70,32 +71,34 @@ def _score(dist, clauses, thetas):
 def scores(planes_l: list, planes_r: list, clauses, thetas, rows: np.ndarray,
            n_cols: int):
     """Yield ``(row_block, score_matrix)`` over ``rows`` x R columns
-    ``[0, n_cols)``, in blocks of rows: float64 scores, exact to float64
-    rounding within ``NEAR`` of a threshold and to float32 rounding
-    elsewhere, where no rounding can change a decision."""
+    ``[0, n_cols)``, in blocks of rows, where ``planes_l`` holds L's rows
+    ``rows`` in that order: float64 scores, exact to float64 rounding
+    within ``NEAR`` of a threshold and to float32 rounding elsewhere, where
+    no rounding can change a decision."""
     used = sorted({f for c in clauses for f in c})
     right = {f: planes_r[f][:n_cols] for f in used}
     step = max(1, BLOCK_ELEMS // max(n_cols, 1))
     for r0 in range(0, rows.size, step):
-        blk = rows[r0:r0 + step]
-        score = _score({f: _dist(planes_l[f][blk], right[f]) for f in used},
+        at = np.arange(r0, min(r0 + step, rows.size))
+        score = _score({f: _dist(planes_l[f][at], right[f]) for f in used},
                        clauses, thetas).astype(np.float64)
         ii, jj = np.nonzero(np.abs(score) < NEAR)
         if ii.size:
-            exact = {f: _dist_pairs(planes_l[f][blk[ii]], right[f][jj])
+            exact = {f: _dist_pairs(planes_l[f][at[ii]], right[f][jj])
                      for f in used}
             score[ii, jj] = _score(exact, clauses, thetas)
-        yield blk, score
+        yield rows[at], score
 
 
 def compare(pairs: np.ndarray, planes_l: list, planes_r: list, clauses,
-            thetas, rows: np.ndarray, n_cols: int) -> dict:
+            thetas, rows: np.ndarray, n_cols: int, n_l: int) -> dict:
     """Compare one query's program pairs ``(k, 2)`` with the reference over
-    ``rows`` x ``[0, n_cols)``.  ``pairs`` may hold rows outside ``rows``;
-    those are not judged."""
+    L rows ``rows`` x R columns ``[0, n_cols)``, where ``planes_l`` holds
+    L's rows ``rows`` in that order and L has ``n_l`` rows.  ``pairs`` may
+    hold rows outside ``rows``; those are not judged."""
     rows = np.asarray(rows, np.int64)
     pairs = np.asarray(pairs, np.int64).reshape(-1, 2)
-    pos = np.full(planes_l[0].shape[0], -1, np.int64)
+    pos = np.full(n_l, -1, np.int64)
     pos[rows] = np.arange(rows.size)
     inside = (pairs[:, 0] >= 0) & (pairs[:, 0] < pos.size) & \
         (pairs[:, 1] >= 0) & (pairs[:, 1] < n_cols)

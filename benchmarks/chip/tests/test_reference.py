@@ -41,7 +41,7 @@ def test_reference_matches_numpy_engine():
     host_l, host_r = t.planes(0)
     out = reference.compare(np.asarray(got.candidates), host_l, host_r,
                             t.clauses, t.thetas, t.check_rows,
-                            host_r[0].shape[0])
+                            host_r[0].shape[0], t.n_l)
     assert out["reference"] > 100
     assert out["duplicates"] == 0
     # the numpy engine multiplies in float32: it may differ only on pairs
@@ -71,8 +71,8 @@ def test_compare_verdicts():
     t = tiny_police()
     host_l, host_r = t.planes(0)
     n_r = host_r[0].shape[0]
-    args = (host_l, host_r, t.clauses, t.thetas, t.check_rows, n_r)
-    score = np.concatenate([s for _, s in reference.scores(*args[:5], n_r)])
+    args = (host_l, host_r, t.clauses, t.thetas, t.check_rows, n_r, t.n_l)
+    score = np.concatenate([s for _, s in reference.scores(*args[:6])])
     good = np.argwhere(score <= 0)
     assert reference.compare(good, *args)["gap"] == 0.0
     dup = np.concatenate([good, good[:1]])

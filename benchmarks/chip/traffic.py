@@ -18,8 +18,10 @@ steps ``prefetch_depth`` deep (``ShardedEngine(prefetch_depth=...)``).
 
 Seeds are any non-negative whole number; each stream of draws hangs off its
 own ``(seed, tag)`` sequence, so the same seed gives the same inputs.
-Resident planes are made on the device and stay there; the check fetches
-them to the host once the window has closed (``fetch``).
+Resident planes are made on the device, sharded over the cell's mesh, and
+stay there (``planes.Deployment.side``); a probe's batches are made on the
+mesh's first device.  Once the window has closed, the check copies to the
+host only the rows it reads, shard by shard (``fetch``).
 """
 
 from __future__ import annotations
@@ -27,7 +29,7 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from planes import Deployment, key
+from planes import Deployment, draw
 
 RESIDENT, BATCH, CHECK_OFFSET, CHECK_ROWS = 0, 1, 2, 3   # draw-stream tags
 MIX_KEYS = {
@@ -39,8 +41,46 @@ MIX_KEYS = {
 }
 
 
+def cell_mesh(chips: int):
+    """The ``(chips, 1)`` ``("data", "model")`` mesh over the first
+    ``chips`` devices: on a host of that many, the engine's own default
+    (``distributed.mesh.make_host_mesh``)."""
+    return jax.make_mesh((chips, 1), ("data", "model"),
+                         devices=jax.devices()[:chips])
+
+
+@jax.jit
+def _take_rows(x, i):
+    return x[i]
+
+
+def fetch_rows(array, rows: np.ndarray) -> np.ndarray:
+    """Rows ``rows`` (sorted, distinct) of a device array split by rows,
+    copied to the host shard by shard: a shard that holds some of them
+    gathers those on its device, padded to the next power of two of their
+    count (at most ``rows.size``), and sends that; one that holds only
+    rows asked for sends itself whole; one that holds none sends nothing.
+    So at most twice the rows asked for cross to the host."""
+    out = np.empty((rows.size,) + array.shape[1:], array.dtype)
+    for sh in array.addressable_shards:
+        lo, hi, _ = sh.index[0].indices(array.shape[0])
+        a, b = np.searchsorted(rows, [lo, hi])
+        if a == b:
+            continue
+        if b - a == hi - lo:
+            out[a:b] = np.asarray(sh.data)
+            continue
+        # padded, so that few programs serve every shard and every seed
+        i = np.zeros(min(1 << int(b - a - 1).bit_length(), rows.size),
+                     np.int32)
+        i[:b - a] = rows[a:b] - lo
+        out[a:b] = np.asarray(_take_rows(sh.data, jax.device_put(
+            i, sh.device)))[:b - a]
+    return out
+
+
 class Traffic:
-    def __init__(self, config: dict, mix: dict, seed: int):
+    def __init__(self, config: dict, mix: dict, seed: int, mesh=None):
         self.config = config
         self.mix = mix
         self.kind = mix["kind"]
@@ -54,34 +94,38 @@ class Traffic:
         self.clauses = self.dep.clauses
         self.thetas = self.dep.thetas
         self.kinds = [f["kind"] for f in config["features"]]
+        self.mesh = mesh if mesh is not None else cell_mesh(1)
+        devices = list(self.mesh.devices.flat)
         planted = config["planted_side"]
         other = "l" if planted == "r" else "r"
-        base = self.dep.rows(key(seed, RESIDENT, 0),
-                             int(config[f"rows_{other}"]))
-        self.resident = {}
+        n_other = int(config[f"rows_{other}"])
         share = float(config["planted_share"])
-        self.batches = []
         if self.kind == "sweep":
-            rows = self.dep.rows(key(seed, RESIDENT, 1),
-                                 int(config[f"rows_{planted}"]),
-                                 partner=base, planted_share=share)
-            self.resident[planted] = rows.encode(planted)
-            del rows
             self.new_side = None
+            shards = self.dep.draws(seed, (RESIDENT, 1),
+                                    int(config[f"rows_{planted}"]), devices)
+            feed = [d for blocks in shards for d in blocks]
         else:
             if mix["new_side"] != planted:
                 raise ValueError(f"probe sends {mix['new_side']} rows; the "
                                  f"deployment plants {planted}")
             self.new_side = planted
-            for b in range(int(mix["distinct_batches"])):
-                rows = self.dep.rows(key(seed, BATCH, b),
-                                     int(mix["batch_rows"]), partner=base,
-                                     planted_share=share)
-                self.batches.append(jax.device_get(rows.encode(planted)))
-        # encoded last, so that the raw and encoded planes of the resident
-        # side are never on the device together with the other side's
-        self.resident[other] = base.encode(other)
-        del base
+            feed = [draw(seed, (BATCH, b), int(mix["batch_rows"]), devices[0])
+                    for b in range(int(mix["distinct_batches"]))]
+        # the other side, with the raw rows the planted rows lie near
+        self.resident = {}
+        self.resident[other], near = self.dep.side(
+            self.dep.draws(seed, (RESIDENT, 0), n_other, devices), other,
+            self.mesh, [self.dep.pick(d, n_other) for d in feed])
+        if self.kind == "sweep":
+            self.resident[planted], _ = self.dep.side(
+                shards, planted, self.mesh, near=near, planted_share=share)
+            self.batches = []
+        else:
+            self.batches = [jax.device_get(self.dep.rows(d, share, rows)
+                                           .encode(planted))
+                            for d, rows in zip(feed, near)]
+        del feed, near
         self.n_l = self.planes_shape("l")
         self.n_r = self.planes_shape("r")
         self._host = None
@@ -106,19 +150,24 @@ class Traffic:
         """Host planes of query ``k``'s new rows."""
         return self.batches[k % len(self.batches)]
 
-    def fetch(self) -> None:
-        """Move the resident planes to the host for the check, and let the
-        device ones go."""
-        self._host = {side: jax.device_get(planes)
+    def fetch(self, n_cols: int | None = None) -> None:
+        """Copy to the host the rows the check reads, shard by shard: L's
+        ``check_rows`` and R's first ``n_cols`` (all of them by default)
+        where that side is resident; and let the device planes go."""
+        n_cols = self.n_r if n_cols is None else n_cols
+        want = {"l": self.check_rows, "r": np.arange(n_cols)}
+        self._host = {side: [fetch_rows(p, want[side]) for p in planes]
                       for side, planes in self.resident.items()}
         self.resident = {}
 
     def planes(self, k: int) -> tuple:
         """Host planes ``(l, r)`` of query ``k``, after ``fetch``: lists,
-        one per feature."""
+        one per feature, of L's ``check_rows`` and R's first rows."""
         host = dict(self._host)
-        if self.new_side is not None:
-            host[self.new_side] = self.batch(k)
+        if self.new_side == "l":
+            host["l"] = [p[self.check_rows] for p in self.batch(k)]
+        elif self.new_side == "r":
+            host["r"] = self.batch(k)
         return host["l"], host["r"]
 
     def checked(self, k: int) -> bool:
