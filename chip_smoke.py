@@ -119,32 +119,13 @@ def _l_slice(feats, rows: int):
     return [dataclasses.replace(f, data_l=f.data_l[:rows]) for f in feats]
 
 
-def band_step_compiled(feats):
+def band_step_compiled():
     """Compile the band-step program the sharded engine last ran, at the
-    staged shapes and shardings it ran with."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.distributed.mesh import l_shard_axes
+    shapes and shardings of its first call."""
     from repro.engine.sharded import ShardedEngine
-    from repro.kernels.fused_cnf_join.ops import _mesh_shardings
 
-    key, fn = next(reversed(ShardedEngine._programs.items()))
-    mesh, _, _, rows_shard, _, r_chunk, n_chunks = key[:7]
-    l_axes = l_shard_axes(mesh)
-    n_l_shards = int(np.prod([mesh.shape[a] for a in l_axes]))
-    d_pad = -(-max(f.data_l.shape[1] for f in feats if f.kind == "embed")
-              // 128) * 128
-    n_vec = sum(f.kind == "embed" for f in feats)
-    n_scal = sum(f.kind == "scalar" for f in feats)
-    pl_n, pr_n = rows_shard * n_l_shards, r_chunk * n_chunks
-    shapes = [(n_vec, pl_n, d_pad), (n_vec, pr_n, d_pad), (n_scal, pl_n),
-              (n_scal, pr_n)]
-    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sh)
-            for s, sh in zip(shapes, _mesh_shardings(mesh, l_axes))]
-    args.append(jax.ShapeDtypeStruct((), jnp.int32,
-                                     sharding=NamedSharding(mesh, P())))
-    return fn.lower(*args).compile()
+    fn = next(reversed(ShardedEngine._programs.values()))
+    return fn.lower(*ShardedEngine._first_args[fn]).compile()
 
 
 def phase_step2(seed: int, n: int = N_ROWS):
@@ -156,7 +137,7 @@ def phase_step2(seed: int, n: int = N_ROWS):
     t1 = time.perf_counter()
     res = ShardedEngine().evaluate(feats, CLAUSES, thetas)
     t2 = time.perf_counter()
-    compiled = band_step_compiled(feats)
+    compiled = band_step_compiled()
     mem = compiled.memory_analysis()
     smoke("b.band_step", memory_analysis=str(mem),
           tpu_custom_call="tpu_custom_call" in compiled.as_text())
@@ -198,10 +179,10 @@ def phase_step2_chips(seed: int, chips: int, n: int = N_ROWS):
     t0 = time.perf_counter()
     multi = eng.evaluate(feats, CLAUSES, thetas)
     t1 = time.perf_counter()
-    mem = band_step_compiled(feats).memory_analysis()
+    mem = band_step_compiled().memory_analysis()
     single = ShardedEngine(mesh=one).evaluate(feats, CLAUSES, thetas)
     t2 = time.perf_counter()
-    staged = stage_planes(feats, CLAUSES, tl=chips * eng.tl,
+    staged = stage_planes(feats, CLAUSES, tl=eng.tl,
                           tr=eng._resolve_r_chunk(1), mesh=mesh,
                           l_axes=("pod", "data"))
     pl_n = staged.emb_l.shape[1]

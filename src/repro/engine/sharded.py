@@ -5,8 +5,12 @@ Layout (see DESIGN.md §3):
   * L rows are sharded over the mesh's L axes — ``("pod", "data")`` on a
     multi-pod mesh, ``("data",)`` otherwise: each of the
     ``l_shards = n_pods * n_data`` shards owns a contiguous block of
-    ``rows_shard = padded_n_l / l_shards`` rows (embedding and scalar
-    planes sliced with ``P(None, ("pod", "data"), ...)``);
+    ``ceil(n_l / l_shards)`` global rows (the last may own fewer), padded
+    on its own device to ``rows_shard``, a multiple of ``tl``
+    (``ops.shard_rows``; embedding and scalar planes sliced with
+    ``P(None, ("pod", "data"), ...)``).  A shard maps its local row to
+    the global id through its first row, a small int32 array split the
+    same way (``StagedPlanes.row0``);
   * R is replicated (the within-pod broadcast) and *streamed*: a host
     loop walks R in ``r_chunk``-column bands.  On a pod mesh the bands
     are **round-robined across pods** — at host step ``k`` pod ``p``
@@ -81,8 +85,10 @@ dense join grows buffers for its own remaining steps, never for later
 evaluations through a shared (serving) engine — ``self.capacity`` is
 construction-time config and is never mutated (the last sweep's final
 sizes are exposed as ``last_sweep_caps`` / ``last_sweep_capacity`` for
-tests and diagnostics).  Padded rows/cols (tile alignment) are filtered
-on the host — O(candidates) work.
+tests and diagnostics).  Padded rows and columns carry the missing
+markers, so they yield no candidate below a threshold of 1; the host
+still drops any pair outside its shard's real rows or past ``n_r`` —
+O(candidates) work.
 
 The engine itself is reusable across stores and meshes: the evaluation
 mesh is resolved per call (a mesh passed at construction wins; otherwise
@@ -319,12 +325,11 @@ class ShardedEngine(CnfEngine):
         stride = max(1, n_chunks // n_pods)
         inner_axes = ("data", "model") if has_model else ("data",)
 
-        def body(emb_l, emb_r, scal_l, scal_r, k):
+        def body(emb_l, emb_r, scal_l, scal_r, row0, k):
             pod = lax.axis_index("pod") if has_pod else jnp.int32(0)
             data = lax.axis_index("data")
             model = lax.axis_index("model") if has_model else jnp.int32(0)
-            shard = pod * n_data + data
-            row0 = shard * rows_shard
+            row0 = row0[0]             # this shard's first global row
             band = (k + pod * stride) % n_chunks
             col0 = band * r_chunk + model * r_sub
             erk = lax.dynamic_slice_in_dim(emb_r, col0, r_sub, axis=1)
@@ -365,7 +370,7 @@ class ShardedEngine(CnfEngine):
         fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, row_spec, None), P(None, None, None),
-                      P(None, row_spec), P(None, None), P()),
+                      P(None, row_spec), P(None, None), P(row_spec), P()),
             out_specs=(P(dev_axes, None), P(dev_axes), P(dev_axes),
                        P(dev_axes)),
             check_vma=False)   # pallas_call has no replication rule
@@ -392,32 +397,37 @@ class ShardedEngine(CnfEngine):
         n_dev = l_shards * n_model
         r_chunk = self._resolve_r_chunk(n_model)
 
-        # pad L to a multiple of l_shards*tl (equal shards, tile-aligned
-        # rows) and R to a multiple of r_chunk (whole stream steps).
+        # pad each L shard to a multiple of tl (tile-aligned rows, on its
+        # own device) and R to a multiple of r_chunk (whole stream steps).
         # stage_planes uploads a host pack once directly onto the mesh
         # layout — or assembles on device from a resident plane set
-        # (serving store) with zero H2D.  The assembly and its D2D reshard
+        # (serving store) with zero H2D.  The assembly and any D2D reshard
         # are memoized on that plane set: a query that hands over the same
         # plane set again stages nothing, one with a new plane set (even
         # over the same resident arrays) assembles again (bytes_staged).
         tracer = current_tracer()
         t_stage0 = time.perf_counter()
         with tracer.annotate("stage_planes"):
-            staged = cnf_ops.stage_planes(feats, clauses,
-                                          tl=l_shards * self.tl, tr=r_chunk,
-                                          mesh=mesh, l_axes=l_axes)
+            staged = cnf_ops.stage_planes(feats, clauses, tl=self.tl,
+                                          tr=r_chunk, mesh=mesh,
+                                          l_axes=l_axes)
         if tracer:
             tracer.record_span(
                 "stage_planes", t_stage0, time.perf_counter(),
                 attrs={"bytes_h2d": staged.bytes_h2d,
                        "bytes_reshard": staged.bytes_reshard,
                        "bytes_staged": staged.bytes_staged,
+                       "bytes_staged_device": staged.bytes_staged_device,
+                       "shards": staged.shards,
                        "pack_hit": staged.pack_hit})
         kclauses = staged.kclauses
         pl_n, pr_n = staged.emb_l.shape[1], staged.emb_r.shape[1]
         rows_shard = pl_n // l_shards
         n_chunks = pr_n // r_chunk
-        args = staged.arrays
+        args = staged.arrays + (staged.row0,)
+        # the end of each shard's real rows, by device of the candidate
+        # buffers (shard-major, model-minor)
+        row_end = np.repeat(staged.row_offsets + staged.row_counts, n_model)
         thetas = tuple(float(t) for t in thetas)
 
         # per-(pod, data, model)-shard capacities, local to THIS sweep:
@@ -500,15 +510,16 @@ class ShardedEngine(CnfEngine):
                 kept += whole[:take].nbytes
                 moved += whole.nbytes
                 out.append((d, whole[:take]))
-            return [seg for _, seg in sorted(out, key=lambda t: t[0])], \
-                kept, moved
+            return sorted(out, key=lambda t: t[0]), kept, moved
 
         def to_pairs(segs) -> list:
-            """Concatenated candidate rows, padding dropped, as tuples."""
+            """Concatenated candidate rows as tuples, dropping any row past
+            its shard's real rows and any column past ``n_r``."""
             if not segs:
                 return []
-            arr = np.concatenate(segs, axis=0)
-            arr = arr[(arr[:, 0] < n_l) & (arr[:, 1] < n_r)]
+            arr = np.concatenate([seg[(seg[:, 0] < row_end[d])
+                                      & (seg[:, 1] < n_r)]
+                                  for d, seg in segs], axis=0)
             return list(zip(arr[:, 0].tolist(), arr[:, 1].tolist()))
 
         depth = self.effective_prefetch_depth

@@ -221,7 +221,7 @@ def _lower_chunk_step(mesh, *, tl, tr, r_chunk, use_kernel) -> tuple:
                         use_kernel=use_kernel)
     l_axes, n_pods, n_data, n_model = _mesh_geometry(mesh)
     l_shards = n_pods * n_data
-    staged = cnf_ops.stage_planes(feats, clauses, tl=l_shards * eng.tl,
+    staged = cnf_ops.stage_planes(feats, clauses, tl=eng.tl,
                                   tr=r_chunk, mesh=mesh, l_axes=l_axes)
     rows_shard = staged.emb_l.shape[1] // l_shards
     n_chunks = staged.emb_r.shape[1] // r_chunk
@@ -229,7 +229,8 @@ def _lower_chunk_step(mesh, *, tl, tr, r_chunk, use_kernel) -> tuple:
     fn = eng._build(mesh, staged.kclauses,
                     tuple(float(t) for t in thetas), rows_shard, cap,
                     r_chunk, n_chunks)
-    hlo = fn.lower(*staged.arrays, jnp.int32(0)).compile().as_text()
+    hlo = fn.lower(*staged.arrays, staged.row0,
+                   jnp.int32(0)).compile().as_text()
     plane_bytes = sum(int(a.nbytes) for a in staged.arrays)
     return hlo, n_pods, n_data * n_model, plane_bytes
 

@@ -8,8 +8,10 @@ Consumes the Perfetto/Chrome trace-event JSON written by
 (obs.export) and prints what a viewer would show, for terminals and CI:
 
   * per-category slice totals (``band_step[7]`` aggregates as
-    ``band_step``), and the on-device extraction's loop trips summed over
-    the pull slices' ``extract_blocks``;
+    ``band_step``), the on-device extraction's loop trips summed over
+    the pull slices' ``extract_blocks``, and the ``stage_planes`` slices'
+    staged bytes (``bytes_staged``, ``bytes_staged_device``: on one
+    device) and built L shards (``shards``) summed over the trace;
   * an ASCII timeline, one row per track (tid), so prefetch-ring overlap
     — ``band_step[k+1]``'s in-flight dispatch window riding over
     ``band_step[k]``'s pull — is visible without a browser;
@@ -117,6 +119,18 @@ def extract_blocks(slices) -> tuple:
     return sum(got), len(got)
 
 
+STAGE_COUNTERS = ("bytes_staged", "bytes_staged_device", "shards")
+
+
+def staging(slices) -> tuple:
+    """({counter: Σ over the ``stage_planes`` slices}, slices carrying
+    them): what the trace's plane staging built."""
+    got = [s["args"] for s in slices
+           if s["name"] == "stage_planes" and "shards" in s["args"]]
+    return {k: sum(a.get(k, 0) for a in got) for k in STAGE_COUNTERS}, \
+        len(got)
+
+
 def critical_path(slices) -> list:
     """Longest root, then its longest child, recursively."""
     by_id = {s["args"]["span_id"]: s for s in slices
@@ -192,6 +206,12 @@ def report(obj) -> str:
     blocks, pulls = extract_blocks(slices)
     if pulls:
         lines.append(f"  extract_blocks: {blocks} over {pulls} pulls")
+    staged, stages = staging(slices)
+    if stages:
+        lines.append(f"  stage_planes: {staged['shards']} shards, "
+                     f"{staged['bytes_staged']} B staged, "
+                     f"{staged['bytes_staged_device']} B on one device, "
+                     f"over {stages} calls")
     lines.append("")
     lines.extend(_timeline(slices, tracks))
     lines.append("")

@@ -132,11 +132,12 @@ class DevicePlaneSet(Sequence):
     a new plane set over the same resident arrays assembles again.
 
     ``mesh`` (inherited from the store) is the sharded engine's default
-    execution mesh for queries over this plane set: the engine lays the
-    assembled planes out over the mesh's L axes once (a device-to-device
-    reshard, memoized in ``pack_cache``), so repeated warm sharded queries
-    — including multi-pod (pod, data, model) meshes — report zero plane
-    reshard bytes (DESIGN.md §4).
+    execution mesh for queries over this plane set: staging builds each
+    L shard on its own device of the mesh, first putting any plane not
+    already split by rows over the mesh's L axes there (a device-to-device
+    reshard, memoized with the layout in ``pack_cache``), so repeated warm
+    sharded queries — including multi-pod (pod, data, model) meshes —
+    report zero plane reshard bytes (DESIGN.md §3, §4).
     """
 
     def __init__(self, feats: list, dev_l: list, dev_r: list, *, mesh=None):

@@ -411,6 +411,17 @@ def test_stage_planes_counts_staged_bytes_and_cache_hits():
     # the host path uploads what it stages
     host = cnf_ops.stage_planes(feats, clauses, tl=32, tr=64)
     assert host.bytes_staged == host.bytes_h2d > 0 and not host.pack_hit
+    # on one device, one shard: the device holds all it staged
+    assert miss["shards"] == 1 and hit["shards"] == 0
+    assert miss["bytes_staged_device"] == miss["bytes_staged"]
+    assert hit["bytes_staged_device"] == 0
+    # trace_report totals them over the trace
+    obj = to_trace_events(tr)
+    assert trace_report.staging(trace_report._slices(obj)) == (
+        {"bytes_staged": miss["bytes_staged"],
+         "bytes_staged_device": miss["bytes_staged"], "shards": 1}, 2)
+    assert f"stage_planes: 1 shards, {miss['bytes_staged']} B staged" in \
+        trace_report.report(obj)
 
 
 def test_annotations_reach_the_profiler_host_plane(tmp_path):

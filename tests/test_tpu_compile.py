@@ -117,6 +117,7 @@ def test_sharded_band_step_compiles_and_fits(topo):
     shapes = [(2, ROWS, D_PAD), (2, ROWS, D_PAD), (1, ROWS), (1, ROWS)]
     args = [_spec(s, sh)
             for s, sh in zip(shapes, _mesh_shardings(mesh, ("data",)))]
+    args.append(_spec((1,), NamedSharding(mesh, P("data")), jnp.int32))
     args.append(_spec((), NamedSharding(mesh, P()), jnp.int32))
     compiled = fn.lower(*args).compile()
     text = compiled.as_text()
@@ -129,3 +130,100 @@ def test_sharded_band_step_compiles_and_fits(topo):
             + mem.temp_size_in_bytes)
     assert used < HBM_BYTES, mem
     assert mem.temp_size_in_bytes <= BAND_STEP_TEMP_BYTES, mem
+
+
+# one police_records_1m shard: 10^6 resident L rows over a (4, 1) mesh of
+# v5e chips, two 3,074-wide embeds and a scalar, a 700-row probe
+SHARD_ROWS, SHARD_PAD, WIDTH, WIDTH_PAD = 250_000, 250_112, 3074, 3200
+STAGE_TEMP_BYTES = 64 * 2**20
+# a device's resident shard plus its staged layout
+SHARD_STAGED_BYTES = 12.7e9
+
+
+def _as_made(shape, sharding, dtype=jnp.float32):
+    """A resident array's spec in the layout a jitted program gives its
+    output, as the planes' generator and a serving store make them: on the
+    TPU a (250,000, 3,074) plane keeps its rows minor (3,074 rows of
+    250,112 lanes pad less than 250,000 of 3,200), the opposite of the
+    kernel layout's."""
+    made = jax.jit(lambda: jnp.zeros(shape, dtype),
+                   out_shardings=sharding).lower().compile()
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=made.output_formats)
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(np.array(topo.devices[:4]).reshape(4, 1), ("data", "model"))
+
+
+# (chips, resident L rows, R rows, R rows padded to whole bands) of each
+# cell's staging: police_records_1m's probe over four chips, and the one-chip
+# probe and sweep of police_records_100k, whose R side is resident too
+STAGINGS = {
+    "police_probe_4chip": (4, 4 * SHARD_ROWS, 700, 1024),
+    "police_probe": (1, 100_000, 700, 1024),
+    "police_sweep": (1, 100_000, 100_000, 100_352),
+}
+
+
+@pytest.mark.parametrize("cell", list(STAGINGS))
+def test_shard_staging_fits_one_chip(topo, cell):
+    """The staging program of each cell writes the kernel layout with no
+    shard-sized temporary and no collective, from resident planes whose
+    rows are minor."""
+    from repro.kernels.fused_cnf_join import ops
+
+    chips, n_l, n_r, pr_n = STAGINGS[cell]
+    mesh = Mesh(np.array(topo.devices[:chips]).reshape(chips, 1),
+                ("data", "model"))
+    stride, rows_pad = ops.shard_rows(n_l, chips, 128)
+    assert rows_pad == (SHARD_PAD if chips == 4 else 100_096)
+    program = ops._stage_program(mesh, ("data",), n_l, stride, rows_pad,
+                                 n_r, pr_n, WIDTH_PAD)
+
+    def spec(shape, *axes):
+        return _as_made(shape, NamedSharding(mesh, P(*axes)))
+
+    resident = spec((n_l, WIDTH), "data", None)
+    assert resident.format.layout.major_to_minor == (1, 0)
+    compiled = program.lower(
+        (resident,) * 2, (spec((n_l,), "data"),),
+        (spec((n_r, WIDTH)),) * 2, (spec((n_r,)),)).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_fdj_stage_shards")
+    assert not any(op in text for op in ("all-gather", "all-to-all",
+                                         "collective-permute"))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < STAGE_TEMP_BYTES, mem
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes <= \
+        SHARD_STAGED_BYTES, mem
+
+
+def test_shard_band_step_fits_beside_the_planes(four_chips):
+    """The band step over one staged police_records_1m shard fits 16 GB
+    beside the shard's resident planes."""
+    from repro.engine.sharded import ShardedEngine
+
+    mesh = four_chips
+    eng = ShardedEngine(interpret=False)
+    r_chunk = eng._resolve_r_chunk(1)
+    cap = max(4096, 4 * SHARD_PAD)
+    fn = eng._build_uncached(mesh, CLAUSES, THETAS, SHARD_PAD, cap, r_chunk,
+                             2, False)
+    n_pad = 4 * SHARD_PAD
+
+    def spec(shape, *axes, dtype=jnp.float32):
+        return _as_made(shape, NamedSharding(mesh, P(*axes)), dtype)
+
+    compiled = fn.lower(
+        spec((2, n_pad, WIDTH_PAD), None, "data", None),
+        spec((2, 2 * r_chunk, WIDTH_PAD)), spec((1, n_pad), None, "data"),
+        spec((1, 2 * r_chunk)), spec((4,), "data", dtype=jnp.int32),
+        spec((), dtype=jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    planes = jax.jit(lambda: jnp.zeros((4 * SHARD_ROWS, WIDTH)),
+                     out_shardings=NamedSharding(mesh, P("data", None))
+                     ).lower().compile().memory_analysis()
+    used = (2 * planes.output_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes + mem.temp_size_in_bytes)
+    assert used < HBM_BYTES, mem
