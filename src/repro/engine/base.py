@@ -96,10 +96,10 @@ class EngineStats:
     # invariant the FeaturePlaneStore exists to provide (DESIGN.md §4).
     bytes_h2d: int = 0
     # bytes moved device -> device to lay store-resident planes out on the
-    # sharded engine's mesh.  Paid at most once per (plane set, mesh): the
-    # sharded assembly is memoized, so warm serving queries report 0 (the
-    # multi-pod serving invariant, DESIGN.md §4).  Always 0 for the
-    # single-device backends.
+    # sharded engine's mesh.  Paid at most once per (resident arrays,
+    # mesh): each side's sharded assembly is memoized on its arrays, so
+    # warm serving queries report 0 (the multi-pod serving invariant,
+    # DESIGN.md §4).  Always 0 for the single-device backends.
     bytes_reshard: int = 0
     # (pair, clause) evaluations actually computed for this chunk — the
     # honest FLOPs proxy behind the conjunct short-circuit (DESIGN.md §3).

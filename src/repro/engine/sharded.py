@@ -401,10 +401,10 @@ class ShardedEngine(CnfEngine):
         # own device) and R to a multiple of r_chunk (whole stream steps).
         # stage_planes uploads a host pack once directly onto the mesh
         # layout — or assembles on device from a resident plane set
-        # (serving store) with zero H2D.  The assembly and any D2D reshard
-        # are memoized on that plane set: a query that hands over the same
-        # plane set again stages nothing, one with a new plane set (even
-        # over the same resident arrays) assembles again (bytes_staged).
+        # (serving store) with zero H2D.  Each side's assembly and any D2D
+        # reshard are memoized on that side's resident arrays, whichever
+        # plane set hands them over: a probe with a new batch over the
+        # same resident side stages only the batch (l_hit, r_hit).
         tracer = current_tracer()
         t_stage0 = time.perf_counter()
         with tracer.annotate("stage_planes"):
@@ -419,7 +419,8 @@ class ShardedEngine(CnfEngine):
                        "bytes_staged": staged.bytes_staged,
                        "bytes_staged_device": staged.bytes_staged_device,
                        "shards": staged.shards,
-                       "pack_hit": staged.pack_hit})
+                       "pack_hit": staged.pack_hit,
+                       "l_hit": staged.l_hit, "r_hit": staged.r_hit})
         kclauses = staged.kclauses
         pl_n, pr_n = staged.emb_l.shape[1], staged.emb_r.shape[1]
         rows_shard = pl_n // l_shards

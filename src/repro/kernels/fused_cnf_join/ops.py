@@ -13,6 +13,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
+import weakref
 from typing import Sequence
 
 import jax
@@ -168,43 +169,63 @@ def _scalar_stack(xs, rows: int, real, side: str):
     return jnp.where(r < real, x, jnp.asarray(_SCALAR_FILL[side], x.dtype))
 
 
-@functools.lru_cache(maxsize=32)
-def _stage_program(mesh, l_axes: tuple, n_l: int, stride: int,
-                   rows_pad: int, n_r: int, pr_n: int, d_pad: int):
-    """The jitted program that writes the kernel layout: each device builds
-    its own L shard from the resident rows it holds (padded per shard to
-    ``rows_pad`` rows and to ``d_pad`` lanes, the features stacked) and the
-    whole replicated R (padded at its end to ``pr_n``), into its outputs
-    with no temporary the size of a shard (``_embed_stack``); L moves no
-    byte between devices.  Without a mesh it is the one-shard program on
-    the arrays' own device."""
+def _side_stack(vec, scal, rows: int, real, d_pad: int, side: str):
+    """One side's embed and scalar stacks (``_embed_stack``,
+    ``_scalar_stack``), or a stack of one missing plane where the CNF names
+    no feature of that kind."""
+    return (_embed_stack(vec, rows, real, d_pad, side) if vec
+            else jnp.zeros((1, rows, d_pad), jnp.float32),
+            _scalar_stack(scal, rows, real, side) if scal
+            else jnp.full((1, rows), _SCALAR_FILL[side], jnp.float32))
 
-    def fdj_stage_shards(vec_l, scal_l, vec_r, scal_r):
+
+def _over_mesh(fn, mesh, in_spec, out_specs):
+    """``fn`` jitted, run per device of ``mesh`` on its own block of the
+    arguments (each ``in_spec``), or on the arguments' own device."""
+    if mesh is None:
+        return jax.jit(fn)
+    return jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=(in_spec, in_spec), out_specs=out_specs,
+        check_vma=False))   # the copy loop's carry starts unsharded
+
+
+@functools.lru_cache(maxsize=32)
+def _stage_l_program(mesh, l_axes: tuple, n_l: int, stride: int,
+                     rows_pad: int, d_pad: int):
+    """The jitted program that writes L's kernel layout: each device builds
+    its own shard from the resident rows it holds, padded per shard to
+    ``rows_pad`` rows and to ``d_pad`` lanes, the features stacked, into
+    its outputs with no temporary the size of a shard (``_embed_stack``);
+    L moves no byte between devices.  Without a mesh it is the one-shard
+    program on the arrays' own device."""
+
+    def fdj_stage_shards(vec_l, scal_l):
         with jax.named_scope("fdj_stage"):
             shard = jnp.int32(0)
             for ax in l_axes if mesh is not None else ():
                 shard = shard * mesh.shape[ax] + lax.axis_index(ax)
             real = jnp.clip(n_l - shard * stride, 0, stride)
-            return (
-                _embed_stack(vec_l, rows_pad, real, d_pad, "l") if vec_l
-                else jnp.zeros((1, rows_pad, d_pad), jnp.float32),
-                _embed_stack(vec_r, pr_n, n_r, d_pad, "r") if vec_r
-                else jnp.zeros((1, pr_n, d_pad), jnp.float32),
-                _scalar_stack(scal_l, rows_pad, real, "l") if scal_l
-                else jnp.full((1, rows_pad), _SCALAR_FILL["l"], jnp.float32),
-                _scalar_stack(scal_r, pr_n, n_r, "r") if scal_r
-                else jnp.full((1, pr_n), _SCALAR_FILL["r"], jnp.float32))
+            return _side_stack(vec_l, scal_l, rows_pad, real, d_pad, "l")
 
-    if mesh is None:
-        return jax.jit(fdj_stage_shards)
     from jax.sharding import PartitionSpec as P
     row = _row_spec(l_axes)
-    fn = jax.shard_map(
-        fdj_stage_shards, mesh=mesh,
-        in_specs=(P(row, None), P(row), P(), P()),
-        out_specs=(P(None, row, None), P(), P(None, row), P()),
-        check_vma=False)   # the copy loop's carry starts unsharded
-    return jax.jit(fn)
+    return _over_mesh(fdj_stage_shards, mesh, P(row),
+                      (P(None, row, None), P(None, row)))
+
+
+@functools.lru_cache(maxsize=32)
+def _stage_r_program(mesh, n_r: int, pr_n: int, d_pad: int):
+    """The jitted program that writes R's kernel layout, whole on every
+    device of ``mesh``: the features stacked, padded at the end to ``pr_n``
+    rows and to ``d_pad`` lanes.  Its own name, apart from L's program, so
+    that a probe's per-query R staging reads as what it is in a trace."""
+
+    def fdj_stage_batch(vec_r, scal_r):
+        with jax.named_scope("fdj_stage"):
+            return _side_stack(vec_r, scal_r, pr_n, n_r, d_pad, "r")
+
+    from jax.sharding import PartitionSpec as P
+    return _over_mesh(fdj_stage_batch, mesh, P(), (P(), P()))
 
 
 def _row_spec(l_axes: tuple):
@@ -229,68 +250,108 @@ def pack_features_device(planes, clauses: Sequence, *, tl: int, tr: int,
 
     Writes the identical values as the host path (padding is constant
     writes, no arithmetic), so kernel outputs are bit-identical whichever
-    path staged the planes.  Assemblies are memoized on the plane set
-    (``_pack_device``).
+    path staged the planes.  Each side's assembly is memoized on its
+    resident arrays (``_pack_device``).
     """
     s = _pack_device(planes, clauses, tl=tl, tr=tr, lane=lane)
     return s.arrays + (s.kclauses, s.n_l, s.n_r)
 
 
+class _SideMemo:
+    """Staged kernel layouts of one side, keyed on the identity of the
+    resident arrays they were copied from and on the layout's geometry.
+
+    The arrays are held only by weak reference: when one of them is freed
+    its entry goes too, so a staged layout neither outlives nor keeps alive
+    the planes it copies.  Contents are never the key: new rows equal to
+    rows staged before are new arrays, and are staged again.  It is one
+    memo for the process because the resident arrays are shared by every
+    plane set built over them, whoever builds it.  Each dict operation is
+    atomic under the interpreter lock; two threads that miss together both
+    stage, and the last one's layout is kept."""
+
+    def __init__(self):
+        self._entries: dict = {}
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+    def get(self, srcs: list, geometry: tuple):
+        entry = self._entries.get((tuple(map(id, srcs)), geometry))
+        if entry is None or any(r() is not x for r, x in zip(entry[0], srcs)):
+            return None
+        return entry[1]
+
+    def put(self, srcs: list, geometry: tuple, staged) -> None:
+        key = (tuple(map(id, srcs)), geometry)
+        entries = self._entries
+
+        def drop(_):
+            entries.pop(key, None)
+
+        entries[key] = (tuple(weakref.ref(x, drop) for x in srcs), staged)
+
+
+_STAGED = _SideMemo()
+
+
 def _pack_device(planes, clauses: Sequence, *, tl: int, tr: int, lane: int,
                  mesh=None, l_axes: tuple = ("data",)) -> "StagedPlanes":
     """The kernel layout of a resident plane set, built on the devices that
-    hold it by one program (``_stage_program``, jitted as
-    ``fdj_stage_shards``): on a mesh, each device writes its own L shard,
+    hold it, one program a side: L's (``_stage_l_program``, jitted as
+    ``fdj_stage_shards``) has each device of a mesh write its own L shard,
     ``shard_rows`` rows padded to a multiple of ``tl``, from the rows it
-    holds, and R whole; no whole-side concatenate, pad or stack, and no
-    temporary the size of a shard.  An L plane not already split by rows
-    over ``l_axes`` is first put so (``bytes_reshard``), an R plane not
-    already whole on every device likewise.
+    holds; R's (``_stage_r_program``, jitted as ``fdj_stage_batch``) writes
+    R whole on every device.  No whole-side concatenate, pad or stack, and
+    no temporary the size of a shard.  An L plane not already split by
+    rows over ``l_axes`` is first put so (``bytes_reshard``), an R plane
+    not already whole on every device likewise.
 
-    The result is memoized on the plane set's ``pack_cache`` per (features,
-    geometry, mesh, axes): a query that hands over the same plane set again
-    stages nothing (``pack_hit``); a new plane set, even over the same
-    resident arrays, stages again.
+    Each side's stacks, with any such reshard, are memoized on that side's
+    resident arrays per geometry (``_SideMemo``): a plane set over arrays
+    staged before, whichever plane set handed them over, reuses their
+    layout (``l_hit``, ``r_hit``), and only the other side is staged.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
     kclauses, vec_ids, scal_ids = _clause_layout(planes, clauses)
     used = sorted({f for c in clauses for f in c})
     n_l = planes[used[0]].data_l.shape[0]
     n_r = planes[used[0]].data_r.shape[0]
+    l_axes = tuple(l_axes)
     l_shards = _l_shards(mesh, l_axes)
     stride, rows_pad = shard_rows(n_l, l_shards, tl)
     pr_n = -(-n_r // tr) * tr
     d_pad = _embed_width(planes, vec_ids, lane)
     offsets, counts = _row_bounds(n_l, l_shards, stride)
-    layout = dict(kclauses=kclauses, n_l=n_l, n_r=n_r, row_offsets=offsets,
-                  row_counts=counts)
+    n_vec, row = len(vec_ids), _row_spec(l_axes)
 
-    cache = getattr(planes, "pack_cache", None)
-    key = (tuple(used), rows_pad, pr_n, d_pad, mesh, tuple(l_axes))
-    if cache is not None and key in cache:
-        *arrays, row0 = cache[key]
-        return StagedPlanes(*arrays, row0=row0, pack_hit=True, **layout)
-
-    def put(xs, spec, rows):
+    def side(srcs, geometry, spec, rows, program):
+        """``(stacks, hit, bytes resharded)`` of one side."""
+        staged = _STAGED.get(srcs, geometry)
+        if staged is not None:
+            return staged, True, 0
         sh = None if mesh is None else NamedSharding(mesh, spec)
-        out = [_on_mesh(x, sh, rows) for x in xs]
-        return tuple(x for x, _ in out), sum(m for _, m in out)
+        moved = [_on_mesh(x, sh, rows) for x in srcs]
+        xs = [x for x, _ in moved]
+        staged = tuple(program(tuple(xs[:n_vec]), tuple(xs[n_vec:])))
+        _STAGED.put(srcs, geometry, staged)
+        return staged, False, sum(m for _, m in moved)
 
-    row = _row_spec(tuple(l_axes))
-    rows_l = l_shards * stride
-    vec_l, m0 = put([planes.device_l(f) for f in vec_ids], P(row, None),
-                    rows_l)
-    scal_l, m1 = put([planes.device_l(f) for f in scal_ids], P(row), rows_l)
-    vec_r, m2 = put([planes.device_r(f) for f in vec_ids], P(), 0)
-    scal_r, m3 = put([planes.device_r(f) for f in scal_ids], P(), 0)
-    program = _stage_program(mesh, tuple(l_axes), n_l, stride, rows_pad,
-                             n_r, pr_n, d_pad)
-    arrays = tuple(program(vec_l, scal_l, vec_r, scal_r))
-    row0 = _row_starts(offsets, mesh, row)
-    if cache is not None:
-        cache[key] = arrays + (row0,)
-    return StagedPlanes(*arrays, row0=row0, bytes_reshard=m0 + m1 + m2 + m3,
-                        **_staged_bytes(arrays), shards=l_shards, **layout)
+    ids = vec_ids + scal_ids
+    (emb_l, scal_l), l_hit, m_l = side(
+        [planes.device_l(f) for f in ids],
+        ("l", n_vec, rows_pad, d_pad, mesh, l_axes), P(row), l_shards * stride,
+        _stage_l_program(mesh, l_axes, n_l, stride, rows_pad, d_pad))
+    (emb_r, scal_r), r_hit, m_r = side(
+        [planes.device_r(f) for f in ids], ("r", n_vec, pr_n, d_pad, mesh),
+        P(), 0, _stage_r_program(mesh, n_r, pr_n, d_pad))
+    built = (() if l_hit else (emb_l, scal_l)) + \
+        (() if r_hit else (emb_r, scal_r))
+    return StagedPlanes(
+        emb_l, emb_r, scal_l, scal_r, kclauses, n_l, n_r, row_offsets=offsets,
+        row_counts=counts, row0=_row_starts(offsets, mesh, row),
+        bytes_reshard=m_l + m_r, **_staged_bytes(built),
+        shards=0 if l_hit else l_shards, l_hit=l_hit, r_hit=r_hit)
 
 
 def _l_shards(mesh, l_axes: tuple) -> int:
@@ -340,9 +401,9 @@ class StagedPlanes:
     serving queries must report as zero, DESIGN.md §4; ``bytes_staged``:
     the staged arrays this call built, on the device from resident planes
     or packed on the host and uploaded; ``bytes_staged_device``: the most
-    of them one device holds; ``shards``: the L shards built.  All three
-    are 0 when ``pack_hit``, the plane set's ``pack_cache`` already holding
-    the arrays."""
+    of them one device holds; ``shards``: the L shards built.  A side whose
+    resident arrays were staged before is reused (``l_hit``, ``r_hit``)
+    and counts in none of these; ``pack_hit`` is both sides reused."""
     emb_l: object
     emb_r: object
     scal_l: object
@@ -358,7 +419,12 @@ class StagedPlanes:
     bytes_staged: int = 0
     bytes_staged_device: int = 0
     shards: int = 0
-    pack_hit: bool = False
+    l_hit: bool = False
+    r_hit: bool = False
+
+    @property
+    def pack_hit(self) -> bool:
+        return self.l_hit and self.r_hit
 
     @property
     def arrays(self) -> tuple:
@@ -390,9 +456,10 @@ def stage_planes(feats: Sequence, clauses: Sequence, *, tl: int, tr: int,
     A plane set exposing ``device_l``/``device_r``
     (serving.planes.DevicePlaneSet) is assembled on device from the
     resident arrays, each shard on its own device (``_pack_device``;
-    bytes_h2d = 0), and memoized on the plane set's ``pack_cache``: a
-    repeated query over the same plane set reports ``bytes_reshard``,
-    ``bytes_staged`` and ``bytes_staged_device`` of 0.  A plain
+    bytes_h2d = 0), each side memoized on its resident arrays: a query
+    over arrays staged before, in this plane set or another, stages only
+    the side that is new (a probe's batch) and reports ``bytes_reshard``,
+    ``bytes_staged`` and ``bytes_staged_device`` of that side.  A plain
     ``FeatureData`` list is packed on the host in the same layout and put
     straight onto it (bytes_h2d = packed bytes).
     """

@@ -11,7 +11,8 @@ Consumes the Perfetto/Chrome trace-event JSON written by
     ``band_step``), the on-device extraction's loop trips summed over
     the pull slices' ``extract_blocks``, and the ``stage_planes`` slices'
     staged bytes (``bytes_staged``, ``bytes_staged_device``: on one
-    device) and built L shards (``shards``) summed over the trace;
+    device), built L shards (``shards``) and sides reused from an earlier
+    staging (``l_hit``, ``r_hit``) summed over the trace;
   * an ASCII timeline, one row per track (tid), so prefetch-ring overlap
     — ``band_step[k+1]``'s in-flight dispatch window riding over
     ``band_step[k]``'s pull — is visible without a browser;
@@ -119,12 +120,14 @@ def extract_blocks(slices) -> tuple:
     return sum(got), len(got)
 
 
-STAGE_COUNTERS = ("bytes_staged", "bytes_staged_device", "shards")
+STAGE_COUNTERS = ("bytes_staged", "bytes_staged_device", "shards",
+                  "l_hit", "r_hit")
 
 
 def staging(slices) -> tuple:
     """({counter: Σ over the ``stage_planes`` slices}, slices carrying
-    them): what the trace's plane staging built."""
+    them): what the trace's plane staging built, and how many times each
+    side's layout was reused instead (a hit counts 1)."""
     got = [s["args"] for s in slices
            if s["name"] == "stage_planes" and "shards" in s["args"]]
     return {k: sum(a.get(k, 0) for a in got) for k in STAGE_COUNTERS}, \
@@ -211,7 +214,8 @@ def report(obj) -> str:
         lines.append(f"  stage_planes: {staged['shards']} shards, "
                      f"{staged['bytes_staged']} B staged, "
                      f"{staged['bytes_staged_device']} B on one device, "
-                     f"over {stages} calls")
+                     f"L reused {staged['l_hit']}, "
+                     f"R reused {staged['r_hit']}, over {stages} calls")
     lines.append("")
     lines.extend(_timeline(slices, tracks))
     lines.append("")

@@ -126,18 +126,19 @@ class DevicePlaneSet(Sequence):
     ``ops.stage_planes`` duck-types on ``device_l``/``device_r`` to
     assemble the kernel layout on device (zero H2D); the numpy engine and
     ``corpus_shape`` use the Sequence-of-FeatureData protocol unchanged.
-    ``pack_cache`` memoizes assembled kernel layouts per padded geometry so
-    repeated warm queries that get this same plane set back (the store's
-    ``provide`` while nothing changed) skip even the on-device reshuffle;
-    a new plane set over the same resident arrays assembles again.
+    Staging memoizes each side's kernel layout on that side's resident
+    arrays, not on the plane set: any plane set over arrays staged before
+    (this one handed over again, another over the same store entries, a
+    delta join's ``slice_r`` over the same L) skips the on-device
+    reshuffle of that side; new arrays are staged.
 
     ``mesh`` (inherited from the store) is the sharded engine's default
     execution mesh for queries over this plane set: staging builds each
     L shard on its own device of the mesh, first putting any plane not
     already split by rows over the mesh's L axes there (a device-to-device
-    reshard, memoized with the layout in ``pack_cache``), so repeated warm
-    sharded queries — including multi-pod (pod, data, model) meshes —
-    report zero plane reshard bytes (DESIGN.md §3, §4).
+    reshard, memoized with the layout), so repeated warm sharded queries —
+    including multi-pod (pod, data, model) meshes — report zero plane
+    reshard bytes (DESIGN.md §3, §4).
     """
 
     def __init__(self, feats: list, dev_l: list, dev_r: list, *, mesh=None):
@@ -145,7 +146,6 @@ class DevicePlaneSet(Sequence):
         self._dev_l = list(dev_l)
         self._dev_r = list(dev_r)
         self.mesh = mesh
-        self.pack_cache: dict = {}
 
     def __len__(self) -> int:
         return len(self.feats)
@@ -177,24 +177,17 @@ class FeaturePlaneStore:
     (pod, data, model) join mesh from ``distributed.mesh.make_join_mesh``
     — to every served ``DevicePlaneSet``: the sharded engine picks it up
     as its default mesh and memoizes the mesh-sharded kernel assembly on
-    the set, so warm sharded queries skip the D2D reshard entirely.
+    the resident arrays, so warm sharded queries skip the D2D reshard
+    entirely.
     """
-
-    _PROVIDED_CACHE_MAX = 4
 
     def __init__(self, byte_budget: Optional[int] = None, *, mesh=None):
         self.byte_budget = byte_budget
         self.mesh = mesh
         self._lock = threading.RLock()
         self._entries: OrderedDict = OrderedDict()
-        self._provided: OrderedDict = OrderedDict()
-        #   (spec identities, fp_l, fp_r) -> (store version, DevicePlaneSet):
-        #   repeated warm queries get the *same* plane-set object back, so
-        #   its pack_cache (assembled kernel layouts) survives across
-        #   queries; invalidated by any store mutation via the version tag
         self._tenant_budgets: OrderedDict = OrderedDict()
         #   tenant -> byte budget (None = registered but unconstrained)
-        self.version = 0              # bumped on any mutation (memo guard)
         self.hits = 0
         self.misses = 0
         self.puts = 0
@@ -233,17 +226,11 @@ class FeaturePlaneStore:
 
     # -- primitives ---------------------------------------------------------
 
-    def _bump(self) -> None:
-        """Any mutation invalidates memoized plane sets; purge them eagerly
-        so stale sets (and the pack assemblies they pin) free promptly."""
-        self.version += 1
-        self._provided.clear()
-
     @property
     def resident_bytes(self) -> int:
         """Device bytes held by raw plane entries.  Derived artifacts —
-        pack assemblies memoized on served DevicePlaneSets — are bounded
-        by ``_PROVIDED_CACHE_MAX`` live sets but are NOT counted against
+        kernel layouts staged from resident planes, which live as long as
+        the planes they copy (``ops._SideMemo``) — are NOT counted against
         ``byte_budget``; size the budget with that padding headroom in
         mind."""
         with self._lock:
@@ -294,7 +281,6 @@ class FeaturePlaneStore:
                                scale, producer=tenant, owners=owners)
             self._entries[key] = entry
             self.puts += 1
-            self._bump()
             self._evict_to_budget(keep=key)
             return entry
 
@@ -303,7 +289,6 @@ class FeaturePlaneStore:
         with self._lock:
             e = self._entries.pop(plane_key(spec, side, fingerprint), None)
             if e is not None:
-                self._bump()
                 if superseded:
                     self.superseded += 1
                 else:
@@ -327,7 +312,6 @@ class FeaturePlaneStore:
         e = self._entries.pop(key)
         self.evictions += 1
         self.evicted_bytes += e.nbytes
-        self._bump()
 
     def _release_lru(self, tenant: str, keep: tuple) -> bool:
         """Release ``tenant``'s least-recently-used entry: a shared entry
@@ -418,16 +402,6 @@ class FeaturePlaneStore:
     def _provide(self, specs, extractor, ledger, *, fp_l, fp_r,
                  embedder, tenant) -> DevicePlaneSet:
         embedder = embedder or getattr(extractor, "_embedder", None)
-        pkey = (tuple((s.key, s.field, s.distance_kind) for s in specs),
-                fp_l, fp_r)
-        memo = self._provided.get(pkey)
-        if memo is not None and memo[0] == self.version:
-            # same counters the per-entry path reports (all entries are
-            # still resident — any eviction/put bumped the version)
-            for spec in specs:
-                self.get(spec, "l", fp_l, tenant=tenant)
-                self.get(spec, "r", fp_r, tenant=tenant)
-            return memo[1]
         feats, dev_l, dev_r = [], [], []
         for spec in specs:
             el = self.get(spec, "l", fp_l, tenant=tenant)
@@ -462,17 +436,7 @@ class FeaturePlaneStore:
                 dev_r.append(er.device)
             feats.append(FeatureData(spec, fd.kind, el.host, er.host,
                                      scale=fd.scale))
-        planes = DevicePlaneSet(feats, dev_l, dev_r, mesh=self.mesh)
-        # memoize only if the whole working set survived the build: a
-        # byte_budget smaller than one query can evict this query's own
-        # entries mid-build, and a memo would then serve evicted arrays
-        # (budget bypassed) while the counting replay misreports misses
-        if all(plane_key(s, "l", fp_l) in self._entries
-               and plane_key(s, "r", fp_r) in self._entries for s in specs):
-            while len(self._provided) >= self._PROVIDED_CACHE_MAX:
-                self._provided.popitem(last=False)
-            self._provided[pkey] = (self.version, planes)
-        return planes
+        return DevicePlaneSet(feats, dev_l, dev_r, mesh=self.mesh)
 
     # -- observability ------------------------------------------------------
 

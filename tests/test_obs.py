@@ -419,9 +419,40 @@ def test_stage_planes_counts_staged_bytes_and_cache_hits():
     obj = to_trace_events(tr)
     assert trace_report.staging(trace_report._slices(obj)) == (
         {"bytes_staged": miss["bytes_staged"],
-         "bytes_staged_device": miss["bytes_staged"], "shards": 1}, 2)
+         "bytes_staged_device": miss["bytes_staged"], "shards": 1,
+         "l_hit": 1, "r_hit": 1}, 2)
     assert f"stage_planes: 1 shards, {miss['bytes_staged']} B staged" in \
         trace_report.report(obj)
+
+
+def test_trace_report_totals_side_hits():
+    """Three queries: both sides new, a new R batch over the resident L,
+    then the same plane set again; the report counts each side's reuse."""
+    import jax.numpy as jnp
+
+    from repro.serving.planes import DevicePlaneSet
+
+    ds = synth.police_records(n_incidents=20, reports_per_incident=2, seed=7)
+    feats, clauses, thetas = _materialized_cnf(ds)
+    dev_l = [jnp.asarray(f.data_l) for f in feats]
+    first = DevicePlaneSet(feats, dev_l, [jnp.asarray(f.data_r)
+                                          for f in feats])
+    probe = DevicePlaneSet(feats, dev_l, [jnp.asarray(f.data_r)
+                                          for f in feats])
+    eng = get_engine("sharded", **_OPTS["sharded"])
+    tr = Tracer()
+    with use_tracer(tr):
+        for planes in (first, probe, probe):
+            eng.evaluate(planes, clauses, thetas)
+    spans = [s.attrs for s in tr.spans() if s.name == "stage_planes"]
+    assert [(a["l_hit"], a["r_hit"], a["pack_hit"]) for a in spans] == [
+        (False, False, False), (True, False, False), (True, True, True)]
+    obj = to_trace_events(tr)
+    totals, calls = trace_report.staging(trace_report._slices(obj))
+    assert (totals["l_hit"], totals["r_hit"], calls) == (2, 1, 3)
+    assert totals["bytes_staged"] == spans[0]["bytes_staged"] + \
+        spans[1]["bytes_staged"]
+    assert "L reused 2, R reused 1, over 3 calls" in trace_report.report(obj)
 
 
 def test_annotations_reach_the_profiler_host_plane(tmp_path):

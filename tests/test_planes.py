@@ -102,8 +102,9 @@ def test_device_pack_matches_host_pack(mk):
             np.testing.assert_array_equal(np.asarray(h), np.asarray(d))
         assert host[4] == dev[4]                       # kclauses
         assert host[5:7] == dev[5:7]                   # (n_l, n_r)
-    # assemblies are memoized per geometry on the plane set
-    assert len(planes.pack_cache) == 2
+    # assemblies are memoized per geometry on the resident arrays
+    assert [cnf_ops.stage_planes(planes, clauses, tl=tl, tr=tr).pack_hit
+            for tl, tr in ((32, 64), (64, 128))] == [True, True]
 
 
 def test_stage_planes_reports_zero_h2d_for_resident_planes():
@@ -172,3 +173,95 @@ def test_counter_delta_between_snapshots():
     d = FeaturePlaneStore.delta(s0, store.snapshot())
     assert d["hits"] == 1 and d["misses"] == 1 and d["bytes_to_device"] == 0
     assert d["resident_bytes"] == store.resident_bytes  # level, not flow
+
+
+# --- each side's kernel layout lives with its resident arrays ---------------
+
+def _sides(ds):
+    """Materialized planes, their CNF, and the resident arrays of each side
+    as a store pins them."""
+    import jax.numpy as jnp
+    specs, clauses, thetas = representative_cnf(ds)
+    feats = SimulatedExtractor(ds).materialize(specs, CostLedger())
+    dev_l = [jnp.asarray(f.data_l) for f in feats]
+    dev_r = [jnp.asarray(f.data_r) for f in feats]
+    return feats, clauses, thetas, dev_l, dev_r
+
+
+def _query(feats, clauses, thetas, dev_l, dev_r):
+    """One sharded query over a new plane set: its candidates and its
+    ``stage_planes`` span."""
+    from repro.engine import get_engine
+    from repro.obs.trace import Tracer, use_tracer
+    from repro.serving.planes import DevicePlaneSet
+    tracer = Tracer()
+    with use_tracer(tracer):
+        got = get_engine("sharded", tl=32, tr=32, r_chunk=64).evaluate(
+            DevicePlaneSet(feats, dev_l, dev_r), clauses, thetas)
+    span = next(s for s in tracer.spans() if s.name == "stage_planes")
+    return got.candidates, span.attrs
+
+
+def test_new_batch_over_resident_side_stages_only_the_batch():
+    """A second plane set over the same resident L arrays and a new R
+    batch reuses L's layout, stages R alone and finds the same pairs as a
+    staging of both sides afresh."""
+    import jax.numpy as jnp
+    from repro.engine import get_engine
+    ds = _police(n=20, seed=7)
+    feats, clauses, thetas, dev_l, dev_r = _sides(ds)
+    _, first = _query(feats, clauses, thetas, dev_l, dev_r)
+    assert not first["l_hit"] and not first["r_hit"]
+    batch = [jnp.asarray(f.data_r) for f in feats]
+    pairs, second = _query(feats, clauses, thetas, dev_l, batch)
+    assert second["l_hit"] and not second["r_hit"]
+    assert not second["pack_hit"] and second["shards"] == 0
+    # R's band stack, padded to whole bands of 64 columns, and no byte of L
+    packed = cnf_ops.pack_features(feats, clauses, tl=32, tr=64)
+    assert second["bytes_staged"] == second["bytes_staged_device"] == \
+        packed[1].nbytes + packed[3].nbytes > 0
+    assert second["bytes_reshard"] == second["bytes_h2d"] == 0
+    fresh, _ = _query(feats, clauses, thetas,
+                      [jnp.array(x, copy=True) for x in dev_l], batch)
+    assert pairs == fresh == get_engine("numpy").evaluate(
+        feats, clauses, thetas).candidates
+    assert pairs
+
+
+@pytest.mark.parametrize("side", ["l", "r"])
+def test_new_arrays_are_staged_again(side):
+    """A side whose arrays are new is staged, even where their contents
+    equal arrays staged before: the memo keys on the arrays, never on a
+    hash of what they hold."""
+    import jax.numpy as jnp
+    ds = _police(n=20, seed=7)
+    feats, clauses, thetas, dev_l, dev_r = _sides(ds)
+    _query(feats, clauses, thetas, dev_l, dev_r)
+    again = {"l": dev_l, "r": dev_r}
+    again[side] = [jnp.asarray(np.asarray(x)) for x in again[side]]
+    _, span = _query(feats, clauses, thetas, again["l"], again["r"])
+    assert span[f"{side}_hit"] is False
+    assert span[f"{'r' if side == 'l' else 'l'}_hit"] is True
+    assert span["bytes_staged"] > 0
+
+
+def test_side_layout_goes_with_its_arrays():
+    """Freeing the last reference to a side's resident arrays drops its
+    staged layout: the memo keeps neither alive."""
+    import gc
+    import weakref
+    from repro.serving.planes import DevicePlaneSet
+    ds = _police(n=20, seed=7)
+    feats, clauses, _, dev_l, dev_r = _sides(ds)
+    before = len(cnf_ops._STAGED)
+    staged = cnf_ops.stage_planes(DevicePlaneSet(feats, dev_l, dev_r),
+                                  clauses, tl=32, tr=64)
+    assert len(cnf_ops._STAGED) == before + 2
+    layout_l, layout_r = weakref.ref(staged.emb_l), weakref.ref(staged.emb_r)
+    del staged, dev_r
+    gc.collect()
+    assert layout_r() is None and layout_l() is not None
+    assert len(cnf_ops._STAGED) == before + 1
+    del dev_l
+    gc.collect()
+    assert layout_l() is None and len(cnf_ops._STAGED) == before

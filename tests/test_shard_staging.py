@@ -60,7 +60,23 @@ def test_resident_shards_staged_in_place(cases, name):
         c["emb_r_bytes"]
     assert c["collectives"] == []
     assert c["whole_l_on_one"] == []
-    assert c["pack_hit"]                 # memoized on the plane set
+    assert c["pack_hit"]                 # memoized on the resident arrays
+
+
+@pytest.mark.parametrize("name", ["interpret", "jnp", "uneven"])
+def test_next_query_stages_only_its_batch(cases, name):
+    """A second query with a new R over the same four L shards reuses L's
+    staged layout, and any reshard that laid L out, and stages only R:
+    each device builds the whole R band stack and no byte moves.  Its
+    pairs equal the first query's over the same R rows."""
+    c = cases[name]
+    span = c["span_again"]
+    assert span["l_hit"] and not span["r_hit"] and not span["pack_hit"]
+    assert span["shards"] == 0
+    assert span["bytes_staged_device"] == span["bytes_staged"] == \
+        c["emb_r_bytes"]
+    assert span["bytes_reshard"] == span["bytes_h2d"] == 0
+    assert c["again_equal"]
 
 
 def test_uneven_shards_from_one_device(cases):

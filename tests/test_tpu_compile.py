@@ -166,11 +166,37 @@ STAGINGS = {
 }
 
 
+def _stage_module():
+    """The benchmark's pattern for the module of L's staging program
+    (``benchmarks/chip/stage_work.py`` ``STAGE``), which its roofline
+    share reads."""
+    import importlib.util
+    import sys
+    chip = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "chip")
+    sys.path.insert(0, chip)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "_bench_stage_work", os.path.join(chip, "stage_work.py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+    finally:
+        sys.path.remove(chip)
+    return mod.STAGE
+
+
+def _module(hlo: str) -> str:
+    """A compiled program's module as a device trace names one run of it:
+    ``HloModule jit_f, ...`` runs as ``jit_f(<id>)``."""
+    return hlo.split()[1].rstrip(",") + "(1)"
+
+
 @pytest.mark.parametrize("cell", list(STAGINGS))
 def test_shard_staging_fits_one_chip(topo, cell):
-    """The staging program of each cell writes the kernel layout with no
+    """L's staging program of each cell writes the kernel layout with no
     shard-sized temporary and no collective, from resident planes whose
-    rows are minor."""
+    rows are minor, under the module name the benchmark's staging
+    roofline reads."""
     from repro.kernels.fused_cnf_join import ops
 
     chips, n_l, n_r, pr_n = STAGINGS[cell]
@@ -178,25 +204,53 @@ def test_shard_staging_fits_one_chip(topo, cell):
                 ("data", "model"))
     stride, rows_pad = ops.shard_rows(n_l, chips, 128)
     assert rows_pad == (SHARD_PAD if chips == 4 else 100_096)
-    program = ops._stage_program(mesh, ("data",), n_l, stride, rows_pad,
-                                 n_r, pr_n, WIDTH_PAD)
+    program = ops._stage_l_program(mesh, ("data",), n_l, stride, rows_pad,
+                                   WIDTH_PAD)
 
     def spec(shape, *axes):
         return _as_made(shape, NamedSharding(mesh, P(*axes)))
 
     resident = spec((n_l, WIDTH), "data", None)
     assert resident.format.layout.major_to_minor == (1, 0)
-    compiled = program.lower(
-        (resident,) * 2, (spec((n_l,), "data"),),
-        (spec((n_r, WIDTH)),) * 2, (spec((n_r,)),)).compile()
+    compiled = program.lower((resident,) * 2,
+                             (spec((n_l,), "data"),)).compile()
     text = compiled.as_text()
     assert text.startswith("HloModule jit_fdj_stage_shards")
+    assert _stage_module().match(_module(text))
     assert not any(op in text for op in ("all-gather", "all-to-all",
                                          "collective-permute"))
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < STAGE_TEMP_BYTES, mem
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes <= \
         SHARD_STAGED_BYTES, mem
+
+
+@pytest.mark.parametrize("cell", list(STAGINGS))
+def test_batch_staging_is_named_apart(topo, cell):
+    """R's staging program, which a probe runs on every query, writes R's
+    band stack whole on each chip in a small temporary, under a module name
+    the benchmark's L staging roofline does not read."""
+    from repro.kernels.fused_cnf_join import ops
+
+    chips, _, n_r, pr_n = STAGINGS[cell]
+    mesh = Mesh(np.array(topo.devices[:chips]).reshape(chips, 1),
+                ("data", "model"))
+
+    def spec(shape):
+        return _as_made(shape, NamedSharding(mesh, P()))
+
+    compiled = ops._stage_r_program(mesh, n_r, pr_n, WIDTH_PAD).lower(
+        (spec((n_r, WIDTH)),) * 2, (spec((n_r,)),)).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_fdj_stage_batch")
+    assert not _stage_module().match(_module(text))
+    assert not any(op in text for op in ("all-gather", "all-to-all",
+                                         "collective-permute"))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < STAGE_TEMP_BYTES, mem
+    # the band stack itself, and tile padding of the one-row scalar stack
+    stack = (2 * WIDTH_PAD + 1) * pr_n * 4
+    assert stack <= mem.output_size_in_bytes < stack + 2**16, mem
 
 
 def test_shard_band_step_fits_beside_the_planes(four_chips):
